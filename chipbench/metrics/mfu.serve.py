@@ -1,0 +1,9 @@
+"""Model FLOP/s utilization of serving: the forward operations of the
+prompt and served tokens of the requests completed in the window, over
+the window and the bf16 peak. In percent."""
+
+
+def read(ctx):
+    c = ctx.counters
+    return 100.0 * c["window_flops"] / c["window_s"] / \
+        ctx.peaks["bf16_flops_per_s"]
