@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro.core import obs
 from repro.core.scheduler import AdaptiveScheduler
 from repro.distributed.activation_sharding import activation_sharding
 from repro.distributed.sharding import batch_axis, batch_spec
@@ -120,17 +121,27 @@ class ElasticMeshSGD:
         """One master-event-loop iteration on the mesh: (a/b) events were
         applied via join/leave, (c) weighted reduce + update inside the jit,
         (d) scheduler feedback from ``measured_power``, (e) broadcast is
-        implicit (params stay sharded)."""
-        batch = self._masked(batch)     # built off the mesh: uncommitted
-        with self._on_mesh():
-            self.state, metrics = self._step(self.state, batch)
-        if measured_power:
-            for w, p in measured_power.items():
-                if w in self.scheduler.stats:
-                    self.scheduler.record(w, latency=0.0,
-                                          vectors=max(1, int(p)),
-                                          compute_time=1.0)
-        out = {k: float(v) for k, v in metrics.items()}
-        out["n_live"] = self.n_live
+        implicit (params stay sharded).
+
+        Spans: ``mlitb.train.step`` (arg ``n_live``) around
+        ``mlitb.train.mask`` (the host builds the work mask and uploads
+        it), ``mlitb.train.dispatch`` (the jitted step, which returns once
+        enqueued) and ``mlitb.train.readback`` (the metrics come back as
+        host floats: the host waits here for the device to finish)."""
+        n_live = self.n_live
+        with obs.span("train.step", n_live=n_live):
+            with obs.span("train.mask"):
+                batch = self._masked(batch)  # built off the mesh
+            with obs.span("train.dispatch"), self._on_mesh():
+                self.state, metrics = self._step(self.state, batch)
+            if measured_power:
+                for w, p in measured_power.items():
+                    if w in self.scheduler.stats:
+                        self.scheduler.record(w, latency=0.0,
+                                              vectors=max(1, int(p)),
+                                              compute_time=1.0)
+            with obs.span("train.readback"):
+                out = {k: float(v) for k, v in metrics.items()}
+        out["n_live"] = n_live
         self.history.append(out)
         return out

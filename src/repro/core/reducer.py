@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import obs
 from repro.core.compression import GradientCompressor
 from repro.core.flatbuf import flat_spec
 from repro.kernels.topk_compress import as_blocks, fused_block_topk_batched
@@ -85,6 +86,21 @@ def weighted_reduce(messages: Sequence[Tuple[PyTree, float]]) -> PyTree:
     for g, _ in messages[1:]:
         acc = jax.tree.map(lambda a, b: a + b.astype(jnp.float32), acc, g)
     return jax.tree.map(lambda a: a / total_n, acc)
+
+
+def scatter_add_rows(x: jnp.ndarray, idx: jnp.ndarray,
+                     upd: jnp.ndarray) -> jnp.ndarray:
+    """``x[w].ravel()[idx[w]] += upd[w]`` for every row ``w`` of ``x``, as
+    ONE scatter over x's flat layout (``idx`` holds row-local flat
+    indices). A scatter over several index dimensions is flattened by the
+    compiler into one that carries no ``op_name``, so its time, and that
+    of its index sort, would fall outside the caller's named scope."""
+    if x.size >= 2 ** 31:
+        raise ValueError(f"{x.shape} entries exceed int32 indexing")
+    W = x.shape[0]
+    rows = jnp.arange(W, dtype=jnp.int32).reshape((W,) + (1,) * (idx.ndim - 1))
+    flat = (rows * (x.size // W) + idx).reshape(-1)
+    return x.reshape(-1).at[flat].add(upd.reshape(-1)).reshape(x.shape)
 
 
 class MasterReducer:
@@ -251,7 +267,17 @@ class MasterReducer:
         is the deadline-late/vacant live-mask: such a row sends nothing
         and its whole corrected gradient stays in the residual. ``kmax``
         is bucketed to the compressor's power-of-two lattice, so at most
-        ~log2(n) variants of this function exist per (W_cap, layout)."""
+        ~log2(n) variants of this function exist per (W_cap, layout).
+
+        Each variant is a named program, so a profile shows it as
+        ``jit_reduce_blocktopk_step``, ``jit_reduce_topk_step`` (topk and
+        randk), ``jit_reduce_dense_step`` or ``jit_reduce_masked_step``.
+        Inside the two compressed ones every op carries one of the named
+        scopes ``reduce/pack`` (flatten and stack the worker rows),
+        ``reduce/compress`` (selection and the keep mask),
+        ``reduce/residual`` (scatter-back of unsent candidates),
+        ``reduce/segment_sum`` (scatter-add of the sent entries) and
+        ``reduce/optimizer``."""
         self.trace_count += 1
         opt = self.optimizer
         comp = self.compressor
@@ -264,17 +290,17 @@ class MasterReducer:
                 # so skip the residual stack + live-mask entirely (the
                 # common case — keeps the hot path at PR-1 speed)
                 @jax.jit
-                def fn(flat, opt_state, gtrees, ns):
+                def reduce_dense_step(flat, opt_state, gtrees, ns):
                     grads = jnp.stack([spec.flatten(t) for t in gtrees])
                     g_bar = jnp.sum(grads, axis=0) / jnp.sum(ns)
                     return opt.update(flat, g_bar, opt_state)
 
-                return fn
+                return reduce_dense_step
 
             # masked variant (kmax == MASKED_UNCOMPRESSED): deferred
             # workers and pending residuals ride the live-mask
             @jax.jit
-            def fn(flat, opt_state, gtrees, res_rows, ns):
+            def reduce_masked_step(flat, opt_state, gtrees, res_rows, ns):
                 g = (jnp.stack([spec.flatten(t) for t in gtrees])
                      + jnp.stack(res_rows))
                 live = (ns > 0).astype(jnp.float32)[:, None]
@@ -284,77 +310,96 @@ class MasterReducer:
                 return (new_flat, new_state,
                         tuple(new_res[i] for i in range(W_cap)))
 
-            return fn
+            return reduce_masked_step
 
         if comp.method == "blocktopk":
             block_w = comp.block_w
 
             @jax.jit
-            def fn(flat, opt_state, gtrees, res_rows, ns, step, k_arr):
-                # (W_cap, R, block_w) stacks, built row-wise (as_blocks)
-                grads = jnp.stack([as_blocks(spec.flatten(t), block_w)
-                                   for t in gtrees])
-                res = jnp.stack([as_blocks(x, block_w) for x in res_rows])
-                R = grads.shape[1]
-                # (W_cap, R, kmax) candidates per worker, descending |.|
-                # per block; res_full assumes ALL kmax candidates sent
-                vals, idx, res_full = fused_block_topk_batched(
-                    grads, res, k=kmax)
-                mask = (jnp.arange(kmax, dtype=jnp.int32)[None, None, :]
-                        < k_arr[:, None, None])
-                sent = jnp.where(mask, vals, 0.0)
-                # candidates a worker did NOT send go back to its residual
-                w_ix = jnp.arange(W_cap, dtype=jnp.int32)[:, None, None]
-                r_ix = jnp.arange(R, dtype=jnp.int32)[None, :, None]
-                new_res = res_full.at[w_ix, r_ix, idx - r_ix * block_w].add(
-                    vals - sent)
-                g_bar = jnp.zeros((n,), jnp.float32).at[
-                    idx.reshape(-1)].add(sent.reshape(-1),
-                                         mode="drop") / jnp.sum(ns)
-                new_flat, new_state = opt.update(flat, g_bar, opt_state)
-                return (new_flat, new_state,
-                        tuple(new_res[i].reshape(-1)[:n]
-                              for i in range(W_cap)))
+            def reduce_blocktopk_step(flat, opt_state, gtrees, res_rows, ns,
+                                      step, k_arr):
+                with jax.named_scope("reduce/pack"):
+                    # (W_cap, R, block_w) stacks, built row-wise
+                    grads = jnp.stack([as_blocks(spec.flatten(t), block_w)
+                                       for t in gtrees])
+                    res = jnp.stack([as_blocks(x, block_w)
+                                     for x in res_rows])
+                with jax.named_scope("reduce/compress"):
+                    # (W_cap, R, kmax) candidates per worker, descending
+                    # |.| per block; res_full assumes ALL kmax sent
+                    vals, idx, res_full = fused_block_topk_batched(
+                        grads, res, k=kmax)
+                    mask = (jnp.arange(kmax, dtype=jnp.int32)[None, None, :]
+                            < k_arr[:, None, None])
+                    sent = jnp.where(mask, vals, 0.0)
+                with jax.named_scope("reduce/residual"):
+                    # candidates a worker did NOT send go back to its
+                    # residual (idx is the row-local flat index)
+                    new_res = scatter_add_rows(res_full, idx, vals - sent)
+                with jax.named_scope("reduce/segment_sum"):
+                    g_bar = jnp.zeros((n,), jnp.float32).at[
+                        idx.reshape(-1)].add(sent.reshape(-1),
+                                             mode="drop") / jnp.sum(ns)
+                with jax.named_scope("reduce/optimizer"):
+                    new_flat, new_state = opt.update(flat, g_bar, opt_state)
+                with jax.named_scope("reduce/residual"):
+                    rows = tuple(new_res[i].reshape(-1)[:n]
+                                 for i in range(W_cap))
+                return new_flat, new_state, rows
 
-            return fn
+            return reduce_blocktopk_step
 
         method = comp.method
         seed = comp.seed
 
         @jax.jit
-        def fn(flat, opt_state, gtrees, res_rows, ns, step, k_arr):
-            grads = jnp.stack([spec.flatten(t) for t in gtrees])
-            res = jnp.stack(res_rows)
-            c = grads + res
-            if method == "topk":
-                _, idx = jax.lax.top_k(jnp.abs(c), kmax)
-            else:                                              # randk
-                base = jax.random.fold_in(jax.random.PRNGKey(seed), step)
-                keys = jax.random.split(base, W_cap)
-                scores = jax.vmap(
-                    lambda key: jax.random.uniform(key, (n,)))(keys)
-                _, idx = jax.lax.top_k(scores, kmax)
-            idx = idx.astype(jnp.int32)
-            vals = jnp.take_along_axis(c, idx, axis=1)
-            mask = (jnp.arange(kmax, dtype=jnp.int32)[None, :]
-                    < k_arr[:, None])
-            sent = jnp.where(mask, vals, 0.0)
-            rows_ix = jnp.arange(W_cap, dtype=jnp.int32)[:, None]
-            # zero exactly the sent entries out of c; unsent candidates
-            # stay in the residual (per-row indices are distinct)
-            new_res = c.at[rows_ix, idx].add(-sent)
-            g_bar = jnp.zeros((n,), jnp.float32).at[idx.reshape(-1)].add(
-                sent.reshape(-1), mode="drop") / jnp.sum(ns)
-            new_flat, new_state = opt.update(flat, g_bar, opt_state)
-            return (new_flat, new_state,
-                    tuple(new_res[i] for i in range(W_cap)))
+        def reduce_topk_step(flat, opt_state, gtrees, res_rows, ns, step,
+                             k_arr):
+            with jax.named_scope("reduce/pack"):
+                grads = jnp.stack([spec.flatten(t) for t in gtrees])
+                res = jnp.stack(res_rows)
+            with jax.named_scope("reduce/compress"):
+                c = grads + res
+                if method == "topk":
+                    _, idx = jax.lax.top_k(jnp.abs(c), kmax)
+                else:                                          # randk
+                    base = jax.random.fold_in(jax.random.PRNGKey(seed),
+                                              step)
+                    keys = jax.random.split(base, W_cap)
+                    scores = jax.vmap(
+                        lambda key: jax.random.uniform(key, (n,)))(keys)
+                    _, idx = jax.lax.top_k(scores, kmax)
+                idx = idx.astype(jnp.int32)
+                vals = jnp.take_along_axis(c, idx, axis=1)
+                mask = (jnp.arange(kmax, dtype=jnp.int32)[None, :]
+                        < k_arr[:, None])
+                sent = jnp.where(mask, vals, 0.0)
+            with jax.named_scope("reduce/residual"):
+                # zero exactly the sent entries out of c; unsent
+                # candidates stay in the residual (per-row indices are
+                # distinct)
+                new_res = scatter_add_rows(c, idx, -sent)
+            with jax.named_scope("reduce/segment_sum"):
+                g_bar = jnp.zeros((n,), jnp.float32).at[
+                    idx.reshape(-1)].add(sent.reshape(-1),
+                                         mode="drop") / jnp.sum(ns)
+            with jax.named_scope("reduce/optimizer"):
+                new_flat, new_state = opt.update(flat, g_bar, opt_state)
+            with jax.named_scope("reduce/residual"):
+                rows = tuple(new_res[i] for i in range(W_cap))
+            return new_flat, new_state, rows
 
-        return fn
+        return reduce_topk_step
 
     def _reduce_and_step_fused(
             self, messages: Dict[str, Tuple[PyTree, float]],
             keep: Optional[Dict[str, int]] = None,
             defer: Optional[Any] = None) -> PyTree:
+        """Spans: ``mlitb.reduce.step`` (args ``W``, ``W_cap``, ``kmax``
+        -- 0 uncompressed, per block for blocktopk -- and ``wire_bytes``)
+        around ``mlitb.reduce.pack`` (the host stacks the worker trees,
+        residual rows, counts and keeps) and ``mlitb.reduce.dispatch``
+        (the jitted call, which returns once the program is enqueued)."""
         if not messages:
             raise ValueError("reduce step with no worker messages")
         defer = frozenset(defer or ())
@@ -364,79 +409,87 @@ class MasterReducer:
         if not on_time or total_n <= 0:
             raise ValueError("reduce step with no on-time samples "
                              "(use defer_to_residual for all-late rounds)")
+        comp = self.compressor
+        if comp is None and keep:
+            raise ValueError("per-worker keep requires a compressor")
         n = self._spec.n
         W = len(names)
         W_cap = self._capacity(W)
         pad = W_cap - W
-        gtrees = (tuple(messages[w][0] for w in names)
-                  + (self._zero_gtree(),) * pad)
-        # ns = 0 is the live-mask: vacant capacity rows AND deferred
-        # (deadline-late) workers carry zero weight in the average
-        ns = np.zeros(W_cap, np.float32)
-        for i, w in enumerate(names):
-            if w not in defer:
-                ns[i] = float(messages[w][1])
-        zeros = jnp.zeros((n,), jnp.float32)
-        res_rows = (tuple(self._residuals.get(w, zeros) for w in names)
-                    + (zeros,) * pad)
-
-        if self.compressor is None:
-            if keep:
-                raise ValueError("per-worker keep requires a compressor")
-            masked = bool(defer) or any(w in self._residuals
-                                        for w in names)
-            if not masked:
-                fn = self._step_fns.get((W_cap, None))
-                if fn is None:
-                    fn = self._step_fns[(W_cap, None)] = \
-                        self._build_step_fn(W_cap, None)
-                self._flat, self.opt_state = fn(
-                    self._flat, self.opt_state, gtrees, ns)
-            else:
-                fn = self._step_fns.get((W_cap, MASKED_UNCOMPRESSED))
-                if fn is None:
-                    fn = self._step_fns[(W_cap, MASKED_UNCOMPRESSED)] = \
-                        self._build_step_fn(W_cap, MASKED_UNCOMPRESSED)
-                self._flat, self.opt_state, new_res = fn(
-                    self._flat, self.opt_state, gtrees, res_rows, ns)
-                # on-time rows leave an all-zero residual: keep the dict
-                # sparse (only deferred mass is worth holding)
+        kmax = 0
+        with obs.span("reduce.step", W=W, W_cap=W_cap) as sp:
+            with obs.span("reduce.pack"):
+                gtrees = (tuple(messages[w][0] for w in names)
+                          + (self._zero_gtree(),) * pad)
+                # ns = 0 is the live-mask: vacant capacity rows AND
+                # deferred (deadline-late) workers carry zero weight
+                ns = np.zeros(W_cap, np.float32)
                 for i, w in enumerate(names):
-                    if w in defer:
-                        self._residuals[w] = new_res[i]
+                    if w not in defer:
+                        ns[i] = float(messages[w][1])
+                zeros = jnp.zeros((n,), jnp.float32)
+                res_rows = (tuple(self._residuals.get(w, zeros)
+                                  for w in names) + (zeros,) * pad)
+                if comp is not None:
+                    # per-worker keep totals, snapped to the compressor's
+                    # lattice (keep=None -> the uniform frac-derived
+                    # default); deferred workers are masked to k = 0
+                    # (nothing on the wire, all mass into the residual)
+                    k_tot = {w: comp.flat_k(n, (keep or {}).get(w))
+                             for w in on_time}
+                    kmax_tot = max(k_tot.values())
+                    if comp.method == "blocktopk":
+                        rows = -(-n // comp.block_w)
+                        kmax = kmax_tot // rows        # per-block keep
+                        k_of = {w: k_tot[w] // rows for w in on_time}
                     else:
-                        self._residuals.pop(w, None)
-            self.last_per_worker_bytes = {w: 4 * n for w in on_time}
-            self.last_wire_bytes = len(on_time) * 4 * n
-        else:
-            comp = self.compressor
-            # per-worker keep totals, snapped to the compressor's lattice
-            # (keep=None -> the uniform frac-derived default); deferred
-            # workers are masked to k = 0 (nothing on the wire, all mass
-            # into the residual)
-            k_tot = {w: comp.flat_k(n, (keep or {}).get(w))
-                     for w in on_time}
-            kmax_tot = max(k_tot.values())
-            if comp.method == "blocktopk":
-                rows = -(-n // comp.block_w)
-                kmax = kmax_tot // rows            # per-block keep
-                k_of = {w: k_tot[w] // rows for w in on_time}
+                        kmax = kmax_tot
+                        k_of = dict(k_tot)
+                    k_arr = jnp.asarray([k_of.get(w, 0) for w in names]
+                                        + [0] * pad, jnp.int32)
+
+            if comp is None:
+                masked = bool(defer) or any(w in self._residuals
+                                            for w in names)
+                key = (W_cap, MASKED_UNCOMPRESSED if masked else None)
+                fn = self._step_fns.get(key)
+                if fn is None:
+                    fn = self._step_fns[key] = self._build_step_fn(*key)
+                with obs.span("reduce.dispatch"):
+                    if masked:
+                        self._flat, self.opt_state, new_res = fn(
+                            self._flat, self.opt_state, gtrees, res_rows,
+                            ns)
+                    else:
+                        self._flat, self.opt_state = fn(
+                            self._flat, self.opt_state, gtrees, ns)
+                if masked:
+                    # on-time rows leave an all-zero residual: keep the
+                    # dict sparse (only deferred mass is worth holding)
+                    for i, w in enumerate(names):
+                        if w in defer:
+                            self._residuals[w] = new_res[i]
+                        else:
+                            self._residuals.pop(w, None)
+                self.last_per_worker_bytes = {w: 4 * n for w in on_time}
+                self.last_wire_bytes = len(on_time) * 4 * n
             else:
-                kmax = kmax_tot
-                k_of = dict(k_tot)
-            k_arr = jnp.asarray([k_of.get(w, 0) for w in names]
-                                + [0] * pad, jnp.int32)
-            fn = self._step_fns.get((W_cap, kmax))
-            if fn is None:
-                fn = self._step_fns[(W_cap, kmax)] = self._build_step_fn(
-                    W_cap, kmax)
-            self._flat, self.opt_state, new_res = fn(
-                self._flat, self.opt_state, gtrees, res_rows, ns,
-                np.asarray(self.step, np.int32), k_arr)
-            for i, w in enumerate(names):
-                self._residuals[w] = new_res[i]
-            self.last_per_worker_bytes = {w: 8 * k_tot[w] for w in on_time}
-            self.last_wire_bytes = sum(self.last_per_worker_bytes.values())
+                fn = self._step_fns.get((W_cap, kmax))
+                if fn is None:
+                    fn = self._step_fns[(W_cap, kmax)] = \
+                        self._build_step_fn(W_cap, kmax)
+                with obs.span("reduce.dispatch"):
+                    self._flat, self.opt_state, new_res = fn(
+                        self._flat, self.opt_state, gtrees, res_rows, ns,
+                        np.asarray(self.step, np.int32), k_arr)
+                for i, w in enumerate(names):
+                    self._residuals[w] = new_res[i]
+                self.last_per_worker_bytes = {w: 8 * k_tot[w]
+                                              for w in on_time}
+                self.last_wire_bytes = sum(
+                    self.last_per_worker_bytes.values())
+            if obs.tracing():
+                sp.set_metadata(kmax=kmax, wire_bytes=self.last_wire_bytes)
         self._params_cache = None
         self.step += 1
         return self.params

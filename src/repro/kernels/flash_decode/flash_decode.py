@@ -99,6 +99,18 @@ def _flash_decode_kernel(pm_ref, pos_ref, live_ref, q_ref, k_ref, v_ref,
         o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
+def grid(batch: int, kv_heads: int, page_blocks: int):
+    """The kernel's grid: every (row, KV head, page block), live or not."""
+    return (batch, kv_heads, page_blocks)
+
+
+def pages_walked(batch: int, page_blocks: int) -> int:
+    """KV pages the grid visits per layer and KV head: the DMA of every
+    (row, page block) step issues, dead pages included."""
+    rows, _, pages = grid(batch, 1, page_blocks)
+    return rows * pages
+
+
 def flash_decode_pallas(q: jnp.ndarray, kpool: jnp.ndarray,
                         vpool: jnp.ndarray, page_map: jnp.ndarray,
                         pos: jnp.ndarray, live: jnp.ndarray, *,
@@ -117,7 +129,7 @@ def flash_decode_pallas(q: jnp.ndarray, kpool: jnp.ndarray,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, K, P),
+        grid=grid(B, K, P),
         in_specs=[
             pl.BlockSpec((1, 1, G, D),
                          lambda b, kh, j, pm, pos, live: (b, kh, 0, 0)),

@@ -255,9 +255,19 @@ def main(argv=None):
               f"peak resident {stats.pages_peak}, prefix hits "
               f"{stats.prefix_hits} ({stats.reused_tokens} tokens never "
               f"re-prefilled), {engine.trie_pages} pages cached for reuse")
-    if engine.decode_kernel == "flash" and not engine.paged:
-        print(f"flash decode: {stats.decode_kv_tokens} live KV tokens "
-              f"streamed (vs {stats.decode_rows_total * max_seq} dense)")
+    if engine.paged:
+        print(f"decode KV: {stats.decode_kv_tokens} live tokens in "
+              f"{stats.decode_pages_live} live pages, of "
+              f"{stats.decode_pages_walked} pages walked")
+    elif engine.decode_kernel == "flash":
+        print(f"decode KV: {stats.decode_kv_tokens} live tokens streamed "
+              f"(vs {stats.decode_rows_total * max_seq} dense)")
+    if stats.ttft_p50 is not None:
+        gap = stats.token_gap_mean
+        print(f"{mode}: time to first token p50={stats.ttft_p50:.3f}s "
+              f"p95={stats.ttft_p95:.3f}s (queue wait p95="
+              f"{stats.queue_wait_p95:.3f}s), mean gap between tokens "
+              f"{'n/a' if gap is None else f'{1e3 * gap:.1f} ms'}")
     if engine.serving.speculative is not None:
         rate = stats.accepted / max(stats.drafted, 1)
         print(f"speculative: drafted {stats.drafted}, accepted "

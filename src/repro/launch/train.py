@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.core import obs
 from repro.core.closure import ResearchClosure
 from repro.core.mesh_engine import ElasticMeshSGD
 from repro.data.datasets import synthetic_lm
@@ -53,15 +54,13 @@ def data_stream(vocab: int, batch: int, seq: int, seed: int = 0):
         yield {"tokens": jnp.asarray(x), "labels": jnp.asarray(y)}
 
 
-def run_mesh_training(cfg, opt, *, steps: int, batch: int, seq: int,
-                      n_workers: int, seed: int = 0, churn=None,
-                      log_every: int = 10, devices=None):
-    """The paper's synchronous data-parallel SGD over a ``(data, model=1)``
-    mesh of ``devices`` (default: every local device). The train state is placed
-    by ``param_specs(..., "train")`` (FSDP over ``data``), the batch is
-    split over ``data``, and each ``data`` slice holds
-    ``n_workers / len(devices)`` virtual workers. Returns
-    ``(engine, losses)``."""
+def build_mesh_training(cfg, opt, *, batch: int, n_workers: int,
+                        seed: int = 0, devices=None) -> ElasticMeshSGD:
+    """The engine of the paper's synchronous data-parallel SGD over a
+    ``(data, model=1)`` mesh of ``devices`` (default: every local device).
+    The train state is placed by ``param_specs(..., "train")`` (FSDP over
+    ``data``), the batch is split over ``data``, and each ``data`` slice
+    holds ``n_workers / len(devices)`` virtual workers."""
     devices = list(devices if devices is not None else jax.devices())
     mesh = make_host_mesh(data=len(devices), model=1, devices=devices)
     params = jax.jit(tf.init_params, static_argnums=1)(
@@ -69,29 +68,52 @@ def run_mesh_training(cfg, opt, *, steps: int, batch: int, seq: int,
     state = make_train_state(params, opt)
     state_sh = to_shardings(param_specs(state, cfg, mesh, "train"), mesh)
     batch_sh = to_shardings(train_batch_specs(mesh, batch), mesh)
-    eng = ElasticMeshSGD(
+    return ElasticMeshSGD(
         train_step=build_train_step(cfg, opt, remat=False),
         state=jax.device_put(state, state_sh), n_workers=n_workers,
         global_batch=batch,
         jit_kwargs=dict(in_shardings=(state_sh, batch_sh),
                         out_shardings=(state_sh, None)),
         mesh=mesh)
+
+
+def run_mesh_training(cfg, opt, *, steps: int, batch: int, seq: int,
+                      n_workers: int, seed: int = 0, churn=None,
+                      log_every: int = 10, devices=None):
+    """Build the engine (``build_mesh_training``) and train it for
+    ``steps`` steps of synthetic LM data, applying ``churn`` events.
+    Returns ``(engine, losses)``.
+
+    Spans: ``mlitb.train.build`` around the build and ``mlitb.train.loop``
+    (arg ``steps``) around the loop. The printed tok/s counts from the
+    end of the first step, which compiles the program."""
+    with obs.span("train.build"):
+        eng = build_mesh_training(cfg, opt, batch=batch,
+                                  n_workers=n_workers, seed=seed,
+                                  devices=devices)
     churn = churn or {}
     stream = data_stream(cfg.vocab_size, batch, seq, seed)
-    t0 = time.time()
     losses = []
-    for i in range(steps):
-        for kind, idx in churn.get(i, []):
-            getattr(eng, kind)(idx)
-            print(f"step {i}: worker {idx} {kind}s "
-                  f"({eng.n_live}/{eng.n_workers} live)")
-        metrics = eng.step(next(stream))
-        losses.append(metrics["loss"])
-        if i % log_every == 0 or i == steps - 1:
-            tok_s = metrics["tokens"] * (i + 1) / max(time.time() - t0, 1e-9)
-            print(f"step {i:5d} loss {metrics['loss']:.4f} "
-                  f"tokens {int(metrics['tokens'])} live {eng.n_live} "
-                  f"({tok_s:.0f} tok/s)")
+    t0 = None
+    with obs.span("train.loop", steps=steps):
+        for i in range(steps):
+            for kind, idx in churn.get(i, []):
+                getattr(eng, kind)(idx)
+                print(f"step {i}: worker {idx} {kind}s "
+                      f"({eng.n_live}/{eng.n_workers} live)")
+            metrics = eng.step(next(stream))
+            losses.append(metrics["loss"])
+            if i % log_every == 0 or i == steps - 1:
+                if t0 is None:       # the first step: compile and run
+                    rate = "compile step"
+                else:                # steps 1..i since the first ended
+                    secs = max(time.time() - t0, 1e-9)
+                    rate = f"{metrics['tokens'] * i / secs:.0f} tok/s"
+                print(f"step {i:5d} loss {metrics['loss']:.4f} "
+                      f"tokens {int(metrics['tokens'])} live {eng.n_live} "
+                      f"({rate})")
+            if t0 is None:
+                t0 = time.time()
     return eng, losses
 
 

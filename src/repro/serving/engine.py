@@ -78,6 +78,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.core import obs
+from repro.kernels.flash_decode import pages_walked
 from repro.models.layers import dtype_of
 from repro.serving.config import (BackpressureConfig, PagingConfig,
                                   SamplingConfig, ServingConfig,
@@ -131,6 +133,11 @@ class Completion:
     latency: float = 0.0            # finish - arrival + 2*client_latency
     version: int = 0                # param version the request was served
                                     # under (pinned at admission)
+    admitted: Optional[float] = None     # the ``now`` of the step that
+                                         # admitted it to a slot
+    first_token: Optional[float] = None  # the ``now`` of the step whose
+                                         # prefill sampled its first token
+                                         # (None: the steps had no clock)
 
 
 @dataclass
@@ -148,9 +155,10 @@ class StepReport:
     #   actually streams, so the cost model can charge per live page
     #   instead of per padded row
     decode_kv: List[int] = field(default_factory=list)
-    # ^ dense flash-decode only: KV tokens READ per decode dispatch (sum
-    #   over live rows of pos + 1) — the flash kernel's pos-bounded scan
-    #   streams only these, so the cost model charges per live token
+    # ^ paged mode and dense flash-decode: live KV tokens per decode
+    #   dispatch (sum over its rows of pos + 1) — what the dense flash
+    #   kernel's pos-bounded scan streams, so the cost model charges it
+    #   per live token; in paged mode the count beside decode_pages
     verify_shapes: List[Tuple[int, int]] = field(default_factory=list)
     # ^ speculative mode: (batch, chunk_cap) of each verify dispatch —
     #   charged like a prefill chunk (it IS one); when non-empty there
@@ -185,8 +193,17 @@ class ServeStats:
     pages_peak: int = 0             # paged: peak pages resident (slots+trie)
     prefix_hits: int = 0            # paged: admissions that reused pages
     reused_tokens: int = 0          # paged: prompt tokens NOT re-prefilled
-    decode_kv_tokens: int = 0       # dense flash: live KV tokens streamed
+    decode_kv_tokens: int = 0       # paged or dense flash: live KV tokens
                                     # across all decode dispatches
+    decode_pages_live: int = 0      # paged: live KV pages across all
+                                    # decode dispatches (sum decode_pages)
+    decode_pages_walked: int = 0    # paged: KV pages the decode programs
+                                    # visit, live or dead
+    ttft_p50: Optional[float] = None   # time to first token, on the same
+    ttft_p95: Optional[float] = None   # footing as latency (None: no clock)
+    queue_wait_p95: Optional[float] = None  # arrival to admission, alike
+    token_gap_mean: Optional[float] = None  # mean gap between a request's
+                                            # tokens after its first
     spec_rounds: int = 0            # speculative: (row, verify) rounds run
     drafted: int = 0                # speculative: draft tokens proposed
     accepted: int = 0               # speculative: draft tokens accepted
@@ -201,6 +218,8 @@ class _SlotState:
     pages: List[int] = field(default_factory=list)  # paged: ordered page ids
     n_shared: int = 0               # paged: leading pages read from the trie
     inserted: int = 0               # paged: prompt pages published so far
+    admitted: Optional[float] = None     # clocks for the Completion
+    first_token: Optional[float] = None
 
 
 class ServingEngine:
@@ -291,10 +310,15 @@ class ServingEngine:
             self.prefix_reuse = bool(serving.paging.prefix_reuse)
             shape = (cfg.n_layers, self.n_pages, cfg.n_kv_heads,
                      self.page_size, cfg.head_dim)
+            # pages one decode dispatch visits: the flash kernel's grid
+            # (the XLA path's page gather reads the same rows x pages)
+            self.pages_walked = pages_walked(self.max_batch,
+                                             self.pages_per_slot)
         else:
             self.page_size = None
             self.pages_per_slot = 0
             self.n_pages = 0
+            self.pages_walked = 0
             self._pool = None
             self._trie = None
             self.prefix_reuse = False
@@ -338,6 +362,8 @@ class ServingEngine:
         self.prefix_hits = 0
         self.reused_tokens = 0
         self.decode_kv_tokens = 0
+        self.decode_pages_live = 0
+        self.decode_pages_walked = 0
         self.spec_rounds = 0
         self.drafted = 0
         self.accepted = 0
@@ -657,7 +683,8 @@ class ServingEngine:
         self._gc_versions()
         return Completion(rid=st.req.rid, prompt_len=len(st.req.prompt),
                           tokens=np.asarray(st.gen, np.int32),
-                          version=st.ver)
+                          version=st.ver, admitted=st.admitted,
+                          first_token=st.first_token)
 
     # -- paged-mode host bookkeeping -----------------------------------
     def _plan_pages(self, req: ServeRequest
@@ -745,68 +772,87 @@ class ServingEngine:
                 self._pool.frozen[page] = True
             st.inserted += 1
 
-    def _run_chunks(self, completed: List[Completion]
-                    ) -> List[Tuple[int, int]]:
+    def _run_chunks(self, completed: List[Completion],
+                    now: Optional[float] = None) -> List[Tuple[int, int]]:
         """Feed one <=prompt_cap chunk to every slot with prompt tokens
         pending, one bucketed dispatch per pinned version present. A
-        slot whose prompt completes samples its first token and goes
-        live (decodable this same step)."""
+        slot whose prompt completes samples its first token (stamped
+        with ``now``) and goes live (decodable this same step)."""
         shapes: List[Tuple[int, int]] = []
         todo = [s for s in range(self.max_batch)
                 if self._slots[s] is not None
                 and self._slots[s].filled < len(self._slots[s].req.prompt)]
         for ver in sorted({self._slots[s].ver for s in todo}):
-            group = [s for s in todo if self._slots[s].ver == ver]
-            clens = [min(len(self._slots[s].req.prompt)
-                         - self._slots[s].filled, self.prompt_cap)
-                     for s in group]
-            bcap = pow2_bucket(len(group))
-            ccap = pow2_bucket(max(clens), lo=self.prompt_bucket_min,
-                               hi=self.prompt_cap)
-            tokens = np.zeros((bcap, ccap), np.int32)
-            off = np.zeros(bcap, np.int32)
-            cl = np.zeros(bcap, np.int32)
-            slots = np.full(bcap, self.max_batch, np.int32)
-            rids = np.zeros(bcap, np.int32)
-            for i, s in enumerate(group):
-                st = self._slots[s]
-                tokens[i, :clens[i]] = \
-                    st.req.prompt[st.filled:st.filled + clens[i]]
-                off[i] = st.filled
-                cl[i] = clens[i]
-                slots[i] = s
-                rids[i] = st.req.rid % (2 ** 31)
-            fn = self._get_chunk_fn(bcap, ccap)
-            if self.paged:
-                rmap, wmap = self._chunk_page_maps(group, bcap)
-                nxt, self.cache = fn(self._versions[ver],
-                                     jnp.asarray(tokens), jnp.asarray(off),
-                                     jnp.asarray(cl), jnp.asarray(rids),
-                                     jnp.asarray(rmap), jnp.asarray(wmap),
-                                     self.cache)
-            else:
-                nxt, self.cache = fn(self._versions[ver],
-                                     jnp.asarray(tokens),
-                                     jnp.asarray(off), jnp.asarray(cl),
-                                     jnp.asarray(slots), jnp.asarray(rids),
-                                     self.cache)
-            nxt = np.asarray(nxt)
-            self.prefill_tokens += bcap * ccap
-            self.prefill_chunks += 1
-            shapes.append((bcap, ccap))
-            for i, s in enumerate(group):
-                st = self._slots[s]
-                st.filled += clens[i]
-                self._pos[s] = st.filled
-                if self.paged and self.prefix_reuse:
-                    self._publish_prompt_pages(st)
-                if st.filled == len(st.req.prompt):
-                    st.gen = [int(nxt[i])]
-                    self._tok[s] = int(nxt[i])
-                    self._live[s] = True
-                    if st.req.max_new <= 1:
-                        completed.append(self._finish(s))
+            with obs.span("serve.prefill") as sp:
+                (bcap, ccap), n_first, n_tokens = self._run_chunk(
+                    [s for s in todo if self._slots[s].ver == ver], ver,
+                    completed, now)
+                shapes.append((bcap, ccap))
+                if obs.tracing():
+                    sp.set_metadata(batch=bcap, chunk=ccap, tokens=n_tokens,
+                                    first_tokens=n_first)
         return shapes
+
+    def _run_chunk(self, group: List[int], ver: int,
+                   completed: List[Completion], now: Optional[float]
+                   ) -> Tuple[Tuple[int, int], int, int]:
+        """One chunk dispatch over the slots ``group`` (all pinned to
+        ``ver``): build its arrays, run it, read back the sampled
+        tokens, advance the slots. Returns its (batch, chunk) bucket,
+        the prompts it completed and the prompt tokens it fed."""
+        clens = [min(len(self._slots[s].req.prompt)
+                     - self._slots[s].filled, self.prompt_cap)
+                 for s in group]
+        bcap = pow2_bucket(len(group))
+        ccap = pow2_bucket(max(clens), lo=self.prompt_bucket_min,
+                           hi=self.prompt_cap)
+        tokens = np.zeros((bcap, ccap), np.int32)
+        off = np.zeros(bcap, np.int32)
+        cl = np.zeros(bcap, np.int32)
+        slots = np.full(bcap, self.max_batch, np.int32)
+        rids = np.zeros(bcap, np.int32)
+        for i, s in enumerate(group):
+            st = self._slots[s]
+            tokens[i, :clens[i]] = \
+                st.req.prompt[st.filled:st.filled + clens[i]]
+            off[i] = st.filled
+            cl[i] = clens[i]
+            slots[i] = s
+            rids[i] = st.req.rid % (2 ** 31)
+        fn = self._get_chunk_fn(bcap, ccap)
+        if self.paged:
+            rmap, wmap = self._chunk_page_maps(group, bcap)
+            nxt, self.cache = fn(self._versions[ver],
+                                 jnp.asarray(tokens), jnp.asarray(off),
+                                 jnp.asarray(cl), jnp.asarray(rids),
+                                 jnp.asarray(rmap), jnp.asarray(wmap),
+                                 self.cache)
+        else:
+            nxt, self.cache = fn(self._versions[ver],
+                                 jnp.asarray(tokens),
+                                 jnp.asarray(off), jnp.asarray(cl),
+                                 jnp.asarray(slots), jnp.asarray(rids),
+                                 self.cache)
+        with obs.span("serve.readback"):
+            nxt = np.asarray(nxt)
+        self.prefill_tokens += bcap * ccap
+        self.prefill_chunks += 1
+        n_first = 0
+        for i, s in enumerate(group):
+            st = self._slots[s]
+            st.filled += clens[i]
+            self._pos[s] = st.filled
+            if self.paged and self.prefix_reuse:
+                self._publish_prompt_pages(st)
+            if st.filled == len(st.req.prompt):
+                n_first += 1
+                st.gen = [int(nxt[i])]
+                st.first_token = now
+                self._tok[s] = int(nxt[i])
+                self._live[s] = True
+                if st.req.max_new <= 1:
+                    completed.append(self._finish(s))
+        return (bcap, ccap), n_first, sum(clens)
 
     def step(self, now: Optional[float] = None) -> StepReport:
         """One engine iteration: admit waiting requests into free slots,
@@ -820,70 +866,101 @@ class ServingEngine:
         engine's ``admission_deadline``) are shed BEFORE admission — a
         stale request must not occupy a slot for a client that has
         already given up. In-flight requests never shed: an admitted
-        request always finishes."""
-        completed: List[Completion] = []
-        shed: List[Shed] = []
-        if now is not None and self._queue:
-            kept: Deque[ServeRequest] = deque()
-            for req in self._queue:
-                dl = req.deadline if req.deadline is not None \
-                    else self.admission_deadline
-                if dl is not None and now - req.arrival > dl:
-                    self._rids_active.discard(req.rid)
-                    s = Shed(req.rid, "deadline", float(now))
-                    self.shed_log.append(s)
-                    shed.append(s)
+        request always finishes. ``now`` also stamps each request's
+        ``admitted`` and ``first_token`` clocks.
+
+        Spans (``repro.core.obs``), nested as listed:
+        ``mlitb.serve.step`` (args ``queued``, ``live_rows``) holds
+        ``mlitb.serve.admit`` (``admitted``, ``shed``, and with ``now``
+        ``queue_wait_max_s``), one ``mlitb.serve.prefill`` per chunk
+        dispatch (``batch``, ``chunk`` — the bucket — ``tokens``,
+        ``first_tokens``) and one ``mlitb.serve.decode`` per decode or
+        verify dispatch (``rows_live``, ``kv_tokens_live`` and, paged,
+        ``kv_pages_live``, ``kv_pages_walked``); each dispatch span holds
+        ``mlitb.serve.readback``, where the host waits for the sampled
+        tokens."""
+        with obs.span("serve.step", queued=len(self._queue)) as sp:
+            if obs.tracing():
+                sp.set_metadata(live_rows=self.n_live)
+            completed: List[Completion] = []
+            shed: List[Shed] = []
+            admitted = self._admit(now, shed)
+            prefill_shapes = self._run_chunks(completed, now)
+
+            dispatches = 0
+            decode_pages: List[int] = []
+            decode_kv: List[int] = []
+            verify_shapes: List[Tuple[int, int]] = []
+            draft_dispatches = 0
+            if self._live.any():
+                if self._spec is not None:
+                    dispatches, draft_dispatches = self._step_speculative(
+                        completed, verify_shapes)
                 else:
-                    kept.append(req)
-            self._queue = kept
-        free = [s for s in range(self.max_batch) if self._slots[s] is None]
-        admitted = 0
-        while self._queue and free:
-            req = self._queue[0]
-            if self.paged:
-                plan = self._plan_pages(req)
-                if plan is None:
-                    break               # strict FIFO: the head of the
-                                        # line waits for pages to free
-                pages, n_shared = plan
-                reused = n_shared * self.page_size
-            else:
-                pages, n_shared, reused = [], 0, 0
-            self._queue.popleft()
-            s = free.pop(0)
-            self._slots[s] = _SlotState(req=req, gen=[], ver=self.version,
-                                        filled=reused, pages=pages,
-                                        n_shared=n_shared,
-                                        inserted=n_shared)
-            self._pos[s] = reused
-            self._live[s] = False
-            admitted += 1
-        self.concurrency_peak = max(
-            self.concurrency_peak,
-            sum(1 for st in self._slots if st is not None))
-        if self.paged:
-            self.pages_peak = max(self.pages_peak, self._pool.n_used)
-
-        prefill_shapes = self._run_chunks(completed)
-
-        dispatches = 0
-        decode_pages: List[int] = []
-        decode_kv: List[int] = []
-        verify_shapes: List[Tuple[int, int]] = []
-        draft_dispatches = 0
-        if self._live.any():
-            if self._spec is not None:
-                dispatches, draft_dispatches = self._step_speculative(
-                    completed, verify_shapes)
-            else:
-                dispatches = self._step_decode(completed, decode_pages,
-                                               decode_kv)
-
+                    dispatches = self._step_decode(completed, decode_pages,
+                                                   decode_kv)
         self.engine_steps += 1
         return StepReport(admitted, prefill_shapes, dispatches,
                           self.max_batch if dispatches else 0, completed,
                           shed, decode_pages, decode_kv, verify_shapes,
                           draft_dispatches)
+
+    def _admit(self, now: Optional[float], shed: List[Shed]) -> int:
+        """Deadline sheds (with ``now``), then FIFO admission into free
+        slots with page planning. Appends the sheds to ``shed``; returns
+        how many requests were admitted."""
+        with obs.span("serve.admit") as sp:
+            if now is not None and self._queue:
+                kept: Deque[ServeRequest] = deque()
+                for req in self._queue:
+                    dl = req.deadline if req.deadline is not None \
+                        else self.admission_deadline
+                    if dl is not None and now - req.arrival > dl:
+                        self._rids_active.discard(req.rid)
+                        s = Shed(req.rid, "deadline", float(now))
+                        self.shed_log.append(s)
+                        shed.append(s)
+                    else:
+                        kept.append(req)
+                self._queue = kept
+            free = [s for s in range(self.max_batch)
+                    if self._slots[s] is None]
+            admitted = 0
+            wait_max = None
+            while self._queue and free:
+                req = self._queue[0]
+                if self.paged:
+                    plan = self._plan_pages(req)
+                    if plan is None:
+                        break           # strict FIFO: the head of the
+                                        # line waits for pages to free
+                    pages, n_shared = plan
+                    reused = n_shared * self.page_size
+                else:
+                    pages, n_shared, reused = [], 0, 0
+                self._queue.popleft()
+                s = free.pop(0)
+                self._slots[s] = _SlotState(
+                    req=req, gen=[], ver=self.version, filled=reused,
+                    pages=pages, n_shared=n_shared, inserted=n_shared,
+                    admitted=now)
+                self._pos[s] = reused
+                self._live[s] = False
+                admitted += 1
+                if now is not None:
+                    wait = now - req.arrival
+                    wait_max = wait if wait_max is None \
+                        else max(wait_max, wait)
+            self.concurrency_peak = max(
+                self.concurrency_peak,
+                sum(1 for st in self._slots if st is not None))
+            if self.paged:
+                self.pages_peak = max(self.pages_peak, self._pool.n_used)
+            if obs.tracing():
+                sp.set_metadata(admitted=admitted, shed=len(shed))
+                if wait_max is not None:
+                    sp.set_metadata(queue_wait_max_s=float(wait_max))
+        return admitted
 
     def _step_decode(self, completed: List[Completion],
                      decode_pages: List[int], decode_kv: List[int]) -> int:
@@ -900,51 +977,66 @@ class ServingEngine:
         vers = sorted({self._slots[s].ver
                        for s in range(self.max_batch) if self._live[s]})
         for ver in vers:
-            group = np.array([self._live[s]
-                              and self._slots[s].ver == ver
-                              for s in range(self.max_batch)], bool)
-            if self.paged:
-                rmap, wmap = self._decode_page_maps(group)
-                decode_pages.append(sum(
-                    int(self._pos[s]) // self.page_size + 1
-                    for s in range(self.max_batch) if group[s]))
-                nxt, self.cache = fn(self._versions[ver],
-                                     jnp.asarray(self._tok[:, None]),
-                                     jnp.asarray(self._pos),
-                                     jnp.asarray(group), self.cache,
-                                     jnp.asarray(rids),
-                                     jnp.asarray(gidx),
-                                     jnp.asarray(rmap),
-                                     jnp.asarray(wmap))
-            else:
-                if self.decode_kernel == "flash":
-                    # the kernel's pos-bounded scan streams only the
-                    # live KV tokens — record them for the cost model
-                    kv = sum(int(self._pos[s]) + 1
-                             for s in range(self.max_batch) if group[s])
-                    decode_kv.append(kv)
-                    self.decode_kv_tokens += kv
-                nxt, self.cache = fn(self._versions[ver],
-                                     jnp.asarray(self._tok[:, None]),
-                                     jnp.asarray(self._pos),
-                                     jnp.asarray(group), self.cache,
-                                     jnp.asarray(rids),
-                                     jnp.asarray(gidx))
-            nxt = np.asarray(nxt)
-            dispatches += 1
-            self.decode_dispatches += 1
-            self.decode_rows_live += int(group.sum())
-            self.decode_rows_total += self.max_batch
-            for s in range(self.max_batch):
-                if not group[s]:
-                    continue
-                st = self._slots[s]
-                st.gen.append(int(nxt[s]))
-                self._pos[s] += 1
-                self._tok[s] = int(nxt[s])
-                if len(st.gen) >= st.req.max_new:
-                    completed.append(self._finish(s))
+            with obs.span("serve.decode") as sp:
+                group = np.array([self._live[s]
+                                  and self._slots[s].ver == ver
+                                  for s in range(self.max_batch)], bool)
+                args = self._decode_args(self._pos[group])
+                if self.paged or self.decode_kernel == "flash":
+                    # all the dense flash kernel's pos-bounded scan
+                    # streams, for the cost model
+                    decode_kv.append(args["kv_tokens_live"])
+                    self.decode_kv_tokens += args["kv_tokens_live"]
+                if self.paged:
+                    rmap, wmap = self._decode_page_maps(group)
+                    decode_pages.append(args["kv_pages_live"])
+                    self.decode_pages_live += args["kv_pages_live"]
+                    self.decode_pages_walked += self.pages_walked
+                    nxt, self.cache = fn(self._versions[ver],
+                                         jnp.asarray(self._tok[:, None]),
+                                         jnp.asarray(self._pos),
+                                         jnp.asarray(group), self.cache,
+                                         jnp.asarray(rids),
+                                         jnp.asarray(gidx),
+                                         jnp.asarray(rmap),
+                                         jnp.asarray(wmap))
+                else:
+                    nxt, self.cache = fn(self._versions[ver],
+                                         jnp.asarray(self._tok[:, None]),
+                                         jnp.asarray(self._pos),
+                                         jnp.asarray(group), self.cache,
+                                         jnp.asarray(rids),
+                                         jnp.asarray(gidx))
+                with obs.span("serve.readback"):
+                    nxt = np.asarray(nxt)
+                dispatches += 1
+                self.decode_dispatches += 1
+                self.decode_rows_live += int(group.sum())
+                self.decode_rows_total += self.max_batch
+                for s in range(self.max_batch):
+                    if not group[s]:
+                        continue
+                    st = self._slots[s]
+                    st.gen.append(int(nxt[s]))
+                    self._pos[s] += 1
+                    self._tok[s] = int(nxt[s])
+                    if len(st.gen) >= st.req.max_new:
+                        completed.append(self._finish(s))
+                if obs.tracing():
+                    sp.set_metadata(**args)
         return dispatches
+
+    def _decode_args(self, pos: np.ndarray) -> Dict[str, int]:
+        """The ``mlitb.serve.decode`` span's counters for a dispatch over
+        rows at positions ``pos``: live KV tokens (``pos + 1`` a row)
+        and, paged, the live pages among the pages walked."""
+        args = {"rows_live": len(pos), "kv_tokens_live": int(pos.sum())
+                + len(pos)}
+        if self.paged:
+            args["kv_pages_live"] = int((pos // self.page_size).sum()) \
+                + len(pos)
+            args["kv_pages_walked"] = self.pages_walked
+        return args
 
     def _step_speculative(self, completed: List[Completion],
                           verify_shapes: List[Tuple[int, int]]
@@ -983,8 +1075,10 @@ class ServingEngine:
         draft_dispatches = 0
         if (kb > 0).any():
             dfn = self._get_draft_fn()
-            drafts = np.asarray(dfn(spec.draft_params, jnp.asarray(win),
-                                    jnp.asarray(hlen)))
+            drafts = dfn(spec.draft_params, jnp.asarray(win),
+                         jnp.asarray(hlen))
+            with obs.span("serve.readback"):
+                drafts = np.asarray(drafts)
             draft_dispatches = 1
             self.drafted += int(kb.sum())
         else:
@@ -994,56 +1088,62 @@ class ServingEngine:
         vers = sorted({self._slots[s].ver
                        for s in range(B) if self._live[s]})
         for ver in vers:
-            group = np.array([self._live[s]
-                              and self._slots[s].ver == ver
-                              for s in range(B)], bool)
-            tokens = np.zeros((B, vcap), np.int32)
-            off = np.zeros(B, np.int32)
-            cl = np.zeros(B, np.int32)
-            for s in range(B):
-                if not group[s]:
-                    continue
-                nkb = int(kb[s])
-                tokens[s, 0] = self._tok[s]
-                tokens[s, 1:1 + nkb] = drafts[s, :nkb]
-                off[s] = self._pos[s]
-                cl[s] = 1 + nkb
-            fn = self._get_verify_fn(vcap)
-            if self.paged:
-                rmap, wmap = self._decode_page_maps(group)
-                nxt, self.cache = fn(self._versions[ver],
-                                     jnp.asarray(tokens),
-                                     jnp.asarray(off), jnp.asarray(cl),
-                                     jnp.asarray(rmap),
-                                     jnp.asarray(wmap), self.cache)
-            else:
-                nxt, self.cache = fn(self._versions[ver],
-                                     jnp.asarray(tokens),
-                                     jnp.asarray(off), jnp.asarray(cl),
-                                     self.cache)
-            nxt = np.asarray(nxt)       # (B, vcap) greedy per chunk col
-            dispatches += 1
-            self.decode_dispatches += 1
-            self.decode_rows_live += int(group.sum())
-            self.decode_rows_total += B
-            verify_shapes.append((B, vcap))
-            for s in range(B):
-                if not group[s]:
-                    continue
-                st = self._slots[s]
-                nkb = int(kb[s])
-                acc = 0
-                while acc < nkb and int(drafts[s, acc]) == int(nxt[s, acc]):
-                    acc += 1
-                emitted = [int(t) for t in drafts[s, :acc]] \
-                    + [int(nxt[s, acc])]
-                st.gen.extend(emitted)
-                self._pos[s] += len(emitted)
-                self._tok[s] = emitted[-1]
-                self.spec_rounds += 1
-                self.accepted += acc
-                if len(st.gen) >= st.req.max_new:
-                    completed.append(self._finish(s))
+            with obs.span("serve.decode") as sp:
+                group = np.array([self._live[s]
+                                  and self._slots[s].ver == ver
+                                  for s in range(B)], bool)
+                pos = self._pos[group]      # a copy: pos before this
+                tokens = np.zeros((B, vcap), np.int32)
+                off = np.zeros(B, np.int32)
+                cl = np.zeros(B, np.int32)
+                for s in range(B):
+                    if not group[s]:
+                        continue
+                    nkb = int(kb[s])
+                    tokens[s, 0] = self._tok[s]
+                    tokens[s, 1:1 + nkb] = drafts[s, :nkb]
+                    off[s] = self._pos[s]
+                    cl[s] = 1 + nkb
+                fn = self._get_verify_fn(vcap)
+                if self.paged:
+                    rmap, wmap = self._decode_page_maps(group)
+                    nxt, self.cache = fn(self._versions[ver],
+                                         jnp.asarray(tokens),
+                                         jnp.asarray(off), jnp.asarray(cl),
+                                         jnp.asarray(rmap),
+                                         jnp.asarray(wmap), self.cache)
+                else:
+                    nxt, self.cache = fn(self._versions[ver],
+                                         jnp.asarray(tokens),
+                                         jnp.asarray(off), jnp.asarray(cl),
+                                         self.cache)
+                with obs.span("serve.readback"):
+                    nxt = np.asarray(nxt)   # (B, vcap) greedy per chunk col
+                dispatches += 1
+                self.decode_dispatches += 1
+                self.decode_rows_live += int(group.sum())
+                self.decode_rows_total += B
+                verify_shapes.append((B, vcap))
+                for s in range(B):
+                    if not group[s]:
+                        continue
+                    st = self._slots[s]
+                    nkb = int(kb[s])
+                    acc = 0
+                    while (acc < nkb
+                           and int(drafts[s, acc]) == int(nxt[s, acc])):
+                        acc += 1
+                    emitted = [int(t) for t in drafts[s, :acc]] \
+                        + [int(nxt[s, acc])]
+                    st.gen.extend(emitted)
+                    self._pos[s] += len(emitted)
+                    self._tok[s] = emitted[-1]
+                    self.spec_rounds += 1
+                    self.accepted += acc
+                    if len(st.gen) >= st.req.max_new:
+                        completed.append(self._finish(s))
+                if obs.tracing():
+                    sp.set_metadata(**self._decode_args(pos))
         return dispatches, draft_dispatches
 
     # ------------------------------------------------------------------
@@ -1070,6 +1170,8 @@ class ServingEngine:
         self.prefix_hits = 0
         self.reused_tokens = 0
         self.decode_kv_tokens = 0
+        self.decode_pages_live = 0
+        self.decode_pages_walked = 0
         self.spec_rounds = 0
         self.drafted = 0
         self.accepted = 0
@@ -1078,8 +1180,17 @@ class ServingEngine:
 
     def _stats(self, completions: List[Completion],
                makespan: float) -> ServeStats:
+        """Run totals. Time to first token and the queue wait are read on
+        the same footing as ``latency``: the latency less the time from
+        the first token's (admission's) step to the finish; they and the
+        mean gap between tokens cover the completions whose clocks were
+        stamped (steps given ``now``)."""
         lats = [c.latency for c in completions]
         gen = sum(int(c.tokens.size) for c in completions)
+        clocked = [c for c in completions if c.first_token is not None]
+        ttft = [c.latency - (c.finish - c.first_token) for c in clocked]
+        wait = [c.latency - (c.finish - c.admitted) for c in clocked]
+        gaps = sum(int(c.tokens.size) - 1 for c in clocked)
         versions: Dict[int, int] = {}
         for c in completions:
             versions[c.version] = versions.get(c.version, 0) + 1
@@ -1103,6 +1214,13 @@ class ServingEngine:
             pages_peak=self.pages_peak, prefix_hits=self.prefix_hits,
             reused_tokens=self.reused_tokens,
             decode_kv_tokens=self.decode_kv_tokens,
+            decode_pages_live=self.decode_pages_live,
+            decode_pages_walked=self.decode_pages_walked,
+            ttft_p50=float(np.percentile(ttft, 50)) if ttft else None,
+            ttft_p95=float(np.percentile(ttft, 95)) if ttft else None,
+            queue_wait_p95=float(np.percentile(wait, 95)) if wait else None,
+            token_gap_mean=sum(c.finish - c.first_token for c in clocked)
+            / gaps if gaps else None,
             spec_rounds=self.spec_rounds, drafted=self.drafted,
             accepted=self.accepted)
 

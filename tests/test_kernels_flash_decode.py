@@ -61,6 +61,19 @@ def _mk_requests(cfg, rng, n, max_prompt=10, max_new=6):
     return reqs
 
 
+def _live_kv(reqs, page_size=None):
+    """Live KV tokens (or, with ``page_size``, pages) the decode
+    dispatches read: a request of prompt P and n tokens decodes n - 1
+    times, at positions P .. P + n - 2, each row holding pos + 1
+    tokens."""
+    out = 0
+    for r in reqs:
+        P = len(r.prompt)
+        for pos in range(P, P + r.max_new - 1):
+            out += pos + 1 if page_size is None else pos // page_size + 1
+    return out
+
+
 def _tokens_by_rid(stats):
     return {c.rid: c.tokens.tolist() for c in stats.completions}
 
@@ -194,6 +207,7 @@ def test_engine_flash_dense_matches_oracle_bit_exact(cfg):
     # the kernel's pos-bounded scan reads fewer KV tokens than the dense
     # rectangle — the counter the cost model charges must show it
     assert 0 < got.decode_kv_tokens < got.decode_rows_total * 32
+    assert got.decode_kv_tokens == _live_kv(reqs)
     assert flash.trace_count == 1 + len(flash.buckets_seen)
 
 
@@ -211,8 +225,14 @@ def test_engine_flash_paged_matches_oracle_bit_exact(cfg):
         max_batch=4, max_seq=32, prompt_cap=8, decode_kernel="flash",
         paging=PagingConfig(page_size=8)))
     ref = _tokens_by_rid(base.run_closed_loop(reqs))
-    assert _tokens_by_rid(flash.run_closed_loop(reqs)) == ref
+    got = flash.run_closed_loop(reqs)
+    assert _tokens_by_rid(got) == ref
     assert flash.trace_count == 1 + len(flash.buckets_seen)
+    # paged mode counts live KV tokens per dispatch too, beside the live
+    # pages and the pages the kernel's grid walks (every row, every page)
+    assert got.decode_kv_tokens == _live_kv(reqs)
+    assert got.decode_pages_live == _live_kv(reqs, page_size=8)
+    assert got.decode_pages_walked == got.decode_dispatches * 4 * (32 // 8)
 
 
 def test_engine_flash_paged_prefix_reuse_still_exact():

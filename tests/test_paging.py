@@ -81,11 +81,17 @@ def test_paged_matches_dense_bit_exact(cfg):
                                                           prompt_cap=8,
                                                           page_size=8))
     ref = _tokens_by_rid(dense.run_closed_loop(reqs))
-    got = _tokens_by_rid(paged.run_closed_loop(reqs))
+    stats = paged.run_closed_loop(reqs)
+    got = _tokens_by_rid(stats)
     assert got == ref
     # trace discipline is unchanged by paging: one decode trace plus one
     # per distinct prefill bucket, regardless of requests served
     assert paged.trace_count == 1 + len(paged.buckets_seen)
+    # live KV tokens per decode dispatch: sum over its rows of pos + 1
+    # (a request decodes n - 1 times, at positions P .. P + n - 2)
+    assert stats.decode_kv_tokens == sum(
+        pos + 1 for r in reqs
+        for pos in range(len(r.prompt), len(r.prompt) + r.max_new - 1))
 
 
 def test_prefix_reuse_is_bit_exact_and_actually_fires():

@@ -80,3 +80,37 @@ def test_block_topk_compiles(one_chip):
     x = jax.ShapeDtypeStruct((N_FLAT,), jnp.float32, sharding=one_chip)
     text = _compile_text(lambda v: block_topk(v, interpret=False), x)
     assert "tpu_custom_call" in text
+
+
+def test_reduce_step_scatters_keep_their_scopes(one_chip):
+    # the reducer's scope shares read each op's op_name: both scatters of
+    # the compiled blocktopk step (the compiler's custom fusions) and the
+    # sort of their indices must keep the named scope that asked for them
+    import re
+
+    from repro.core.compression import GradientCompressor
+    from repro.core.reducer import MasterReducer
+    from repro.optim import get_optimizer
+
+    leaves = {"a": (1024, 1024), "b": (4096,)}
+    red = MasterReducer({k: jnp.ones(v) for k, v in leaves.items()},
+                        get_optimizer("adagrad", lr=0.05),
+                        GradientCompressor("blocktopk", frac=4 / 128,
+                                           block_w=128))
+
+    def S(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    n = red.flat_n
+    tree = {k: S(v) for k, v in leaves.items()}
+    text = red._build_step_fn(W, 4).lower(
+        S((n,)), jax.tree.map(lambda x: S(x.shape, x.dtype), red.opt_state),
+        (tree,) * W, (S((n,)),) * W, S((W,)), S((), jnp.int32),
+        S((W,), jnp.int32)).compile().as_text()
+    entry = text[text.index("\nENTRY"):].split("\n}")[0].splitlines()
+    heavy = [ln for ln in entry if " sort(" in ln
+             or (" fusion(" in ln and "kind=kCustom" in ln)]
+    assert len(heavy) == 3, heavy
+    for ln in heavy:
+        assert re.search(r'op_name="[^"]*/reduce/(residual|segment_sum)/',
+                         ln), ln[:160]
