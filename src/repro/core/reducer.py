@@ -18,12 +18,16 @@ Two execution paths:
 - **fused (default).** Every worker tree is raveled into one contiguous
   fp32 buffer (core.flatbuf), the per-worker channel (error-feedback add,
   sparsify, packed emission, residual update) runs over the stacked
-  (num_workers, n) buffer, and the reduce is a single scatter-add
-  segment-sum followed by the optimizer step — ALL inside one jitted
-  function per (worker CAPACITY, max keep bucket). O(1) dispatches per
-  iteration instead of O(workers x leaves). Ragged per-worker keeps
-  (bandwidth-adaptive ``frac_w``, core/adaptive_frac.py) ride the same
-  dispatch: pad-to-the-largest-bucket plus a runtime mask, no retrace.
+  (num_workers, n) buffer, and the reduce is a segment-sum of the sent
+  entries followed by the optimizer step — ALL inside one jitted
+  function per (worker CAPACITY, max keep bucket). blocktopk picks are
+  local to their 128-entry block, so its residual and segment-sum are
+  rebuilt block by block with compare-and-select passes
+  (``add_in_blocks``); topk/randk picks are global and scatter-add.
+  O(1) dispatches per iteration instead of O(workers x leaves). Ragged
+  per-worker keeps (bandwidth-adaptive ``frac_w``,
+  core/adaptive_frac.py) ride the same dispatch: pad-to-the-largest-
+  bucket plus a runtime mask, no retrace.
 
   The worker axis is CAPACITY-PADDED for churn (docs/elastic_training.md):
   the step fn is traced for ``W_cap`` = the next power of two >= the
@@ -74,6 +78,15 @@ PyTree = Any
 # variants key on their kmax >= 1; None keys the plain uncompressed fn)
 MASKED_UNCOMPRESSED = -1
 
+# blocktopk rebuilds its residual and segment-sum with one compare-and-
+# select pass per pick (``add_in_blocks``) while W_cap * kmax is at most
+# this, else scatter-adds. On a TPU v5e the rebuilt step ran 6.4-9.3x
+# faster than the scatters at every W_cap * kmax from 16 to 1024 (PERF.md,
+# "the crossover"), but its compile time grows with the picks (a first,
+# compiling call of 64 s at 1024 against the scatters' 18 s) and nothing
+# above 1024 was measured. A property of the traced shape, not a setting.
+BLOCK_REBUILD_MAX_PICKS = 1024
+
 
 def weighted_reduce(messages: Sequence[Tuple[PyTree, float]]) -> PyTree:
     """messages: [(grad_sum_tree, n_samples)] -> mean-gradient tree."""
@@ -101,6 +114,20 @@ def scatter_add_rows(x: jnp.ndarray, idx: jnp.ndarray,
     rows = jnp.arange(W, dtype=jnp.int32).reshape((W,) + (1,) * (idx.ndim - 1))
     flat = (rows * (x.size // W) + idx).reshape(-1)
     return x.reshape(-1).at[flat].add(upd.reshape(-1)).reshape(x.shape)
+
+
+def add_in_blocks(x: jnp.ndarray, at: jnp.ndarray, upd: jnp.ndarray,
+                  where: jnp.ndarray) -> jnp.ndarray:
+    """``x[..., r, c] += upd[..., r, j]`` for every pick ``j`` of row
+    ``r`` with ``at[..., r, j] == where[..., r, c]`` (``where`` broadcast
+    to ``x``): each pick addresses a lane of its own row. One statically
+    unrolled compare-and-select pass a pick, bound by memory, where a
+    scatter would sort its indices first. Lanes no pick names keep their
+    bits; repeated picks add up, as a scatter-add's do."""
+    for j in range(at.shape[-1]):
+        hit = at[..., j:j + 1] == where
+        x = jnp.where(hit, x + upd[..., j:j + 1], x)
+    return x
 
 
 class MasterReducer:
@@ -246,7 +273,7 @@ class MasterReducer:
         parameter buffer happens inside this single dispatch: per-worker
         ravel into the flat layout, the compression channel (error-
         feedback add + sparsify + packed emission + residual update), the
-        scatter-add segment-sum reduce, and the optimizer step.
+        segment-sum reduce, and the optimizer step.
 
         The worker axis is padded to ``W_cap`` (power-of-two, monotone
         across the reducer's lifetime): the live fleet occupies a prefix
@@ -262,7 +289,7 @@ class MasterReducer:
         ``k_arr`` masks each worker down to its own keep — selection
         emits in descending-|.| order, so the first ``k_arr[w]`` entries
         ARE worker w's top-k. Masked-off candidates carry value 0 into
-        the segment-sum (scatter no-ops, never on the wire) and are
+        the segment-sum (no-ops, never on the wire) and are
         returned to the worker's error-feedback residual. ``k_arr[w] = 0``
         is the deadline-late/vacant live-mask: such a row sends nothing
         and its whole corrected gradient stays in the residual. ``kmax``
@@ -275,9 +302,15 @@ class MasterReducer:
         Inside the two compressed ones every op carries one of the named
         scopes ``reduce/pack`` (flatten and stack the worker rows),
         ``reduce/compress`` (selection and the keep mask),
-        ``reduce/residual`` (scatter-back of unsent candidates),
-        ``reduce/segment_sum`` (scatter-add of the sent entries) and
-        ``reduce/optimizer``."""
+        ``reduce/residual`` (unsent candidates added back to the
+        residual), ``reduce/segment_sum`` (the sent entries summed over
+        workers) and ``reduce/optimizer``.
+
+        blocktopk's picks are local to their block, so while ``W_cap *
+        kmax <= BLOCK_REBUILD_MAX_PICKS`` both of those are rebuilt over
+        the ``(W_cap, R, block_w)`` layout by ``add_in_blocks``, one
+        compare-and-select pass a pick; above it, and for topk/randk,
+        whose picks are global, they are scatter-adds."""
         self.trace_count += 1
         opt = self.optimizer
         comp = self.compressor
@@ -314,6 +347,7 @@ class MasterReducer:
 
         if comp.method == "blocktopk":
             block_w = comp.block_w
+            in_blocks = W_cap * kmax <= BLOCK_REBUILD_MAX_PICKS
 
             @jax.jit
             def reduce_blocktopk_step(flat, opt_state, gtrees, res_rows, ns,
@@ -335,16 +369,39 @@ class MasterReducer:
                 with jax.named_scope("reduce/residual"):
                     # candidates a worker did NOT send go back to its
                     # residual (idx is the row-local flat index)
-                    new_res = scatter_add_rows(res_full, idx, vals - sent)
-                with jax.named_scope("reduce/segment_sum"):
-                    g_bar = jnp.zeros((n,), jnp.float32).at[
-                        idx.reshape(-1)].add(sent.reshape(-1),
-                                             mode="drop") / jnp.sum(ns)
-                with jax.named_scope("reduce/optimizer"):
-                    new_flat, new_state = opt.update(flat, g_bar, opt_state)
-                with jax.named_scope("reduce/residual"):
+                    unsent = vals - sent
+                    if in_blocks:
+                        # the rows that hold entries below n (past them
+                        # lie zeros only) and each row's first entry
+                        Rn = -(-n // block_w)
+                        first = jnp.arange(Rn, dtype=jnp.int32)[:, None] \
+                            * block_w
+                        lanes = jnp.arange(block_w, dtype=jnp.int32)
+                        new_res = [add_in_blocks(res_full[i, :Rn],
+                                                 idx[i, :Rn] - first,
+                                                 unsent[i, :Rn], lanes)
+                                   for i in range(W_cap)]
+                    else:
+                        new_res = scatter_add_rows(res_full, idx, unsent)
                     rows = tuple(new_res[i].reshape(-1)[:n]
                                  for i in range(W_cap))
+                with jax.named_scope("reduce/segment_sum"):
+                    if in_blocks:
+                        # picks against each entry's flat position: no
+                        # compare is shared with the residual's, so none
+                        # is kept in memory between the two passes
+                        pos = first + lanes
+                        g = jnp.zeros((Rn, block_w), jnp.float32)
+                        for i in range(W_cap):
+                            g = add_in_blocks(g, idx[i, :Rn], sent[i, :Rn],
+                                              pos)
+                        g_bar = g.reshape(-1)[:n] / jnp.sum(ns)
+                    else:
+                        g_bar = jnp.zeros((n,), jnp.float32).at[
+                            idx.reshape(-1)].add(sent.reshape(-1),
+                                                 mode="drop") / jnp.sum(ns)
+                with jax.named_scope("reduce/optimizer"):
+                    new_flat, new_state = opt.update(flat, g_bar, opt_state)
                 return new_flat, new_state, rows
 
             return reduce_blocktopk_step

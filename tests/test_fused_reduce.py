@@ -8,6 +8,8 @@
   the per-worker dense path on a 4-worker `mlitb_cnn` step;
 - packed wire bytes match the compressor's accounting.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,9 +17,10 @@ import pytest
 
 from repro.core.compression import (GradientCompressor, decompress_flat)
 from repro.core.flatbuf import flat_spec
-from repro.core.reducer import MasterReducer
+from repro.core.reducer import MasterReducer, scatter_add_rows
 from repro.core.simulation import make_cnn_problem
 from repro.data.datasets import synthetic_mnist
+from repro.kernels.topk_compress import as_blocks, fused_block_topk_batched
 from repro.optim import adagrad, sgd
 
 
@@ -329,3 +332,121 @@ def test_defer_to_residual_accumulates():
                                   np.full(8, 2.0, np.float32))
     red.drop_worker("a")
     assert "a" not in red._residuals
+
+
+# ---------------------------------------------------------------------------
+# blocktopk: the block-local rebuild against the scatter-adds it replaces
+# ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnames=("kmax", "block_w", "opt"))
+def _blocktopk_scatter_reference(flat, opt_state, grads, res, ns, k_arr,
+                                 kmax, block_w, opt):
+    """One blocktopk master step with both reconstructions as scatter-adds
+    over the flat layout: unsent candidates back into each residual, and
+    the sent entries summed into the reduced gradient."""
+    n = flat.shape[0]
+    vals, idx, res_full = fused_block_topk_batched(
+        jnp.stack([as_blocks(g, block_w) for g in grads]),
+        jnp.stack([as_blocks(r, block_w) for r in res]), k=kmax)
+    sent = jnp.where(jnp.arange(kmax)[None, None, :]
+                     < k_arr[:, None, None], vals, 0.0)
+    new_res = scatter_add_rows(res_full, idx, vals - sent)
+    g_bar = jnp.zeros((n,), jnp.float32).at[idx.reshape(-1)].add(
+        sent.reshape(-1), mode="drop") / jnp.sum(ns)
+    new_flat, new_state = opt.update(flat, g_bar, opt_state)
+    return new_flat, new_state, [new_res[i].reshape(-1)[:n]
+                                 for i in range(len(grads))]
+
+
+def _pad_to_capacity(xs, fill):
+    """``xs`` and ``fill`` in the vacant rows of the reducer's worker
+    capacity (the next power of two)."""
+    return xs + [fill] * ((1 << (len(xs) - 1).bit_length()) - len(xs))
+
+
+@pytest.mark.parametrize("case", ["in_blocks", "above_crossover"])
+def test_blocktopk_rebuild_matches_scatter_reference(case, monkeypatch):
+    """Three reduce steps (ragged keeps, a deferred worker, a vacant
+    capacity row, all-zero and one-entry blocks, n off the 128 x 128
+    tiling) match the scatter reference: residuals bit for bit, params
+    and AdaGrad state within 1 ulp. Below the crossover the step takes
+    the block-local rebuild, above it the scatter-adds."""
+    import repro.core.reducer as rd
+
+    block_w = 128
+    if case == "in_blocks":
+        n, kmax, names = 20_000, 4, ["a", "b", "c"]          # W_cap 4
+    else:
+        n, kmax = 1_000, block_w
+        names = [f"w{i}" for i in range(rd.BLOCK_REBUILD_MAX_PICKS
+                                        // kmax + 1)]
+    W_cap = 1 << (len(names) - 1).bit_length()
+    assert (W_cap * kmax <= rd.BLOCK_REBUILD_MAX_PICKS) == (
+        case == "in_blocks")
+    rows = -(-n // block_w)
+    rng = np.random.RandomState(14)
+    grads = {}
+    for i, w in enumerate(names):
+        g = rng.randn(n).astype(np.float32)
+        g[:3 * block_w] = 0.0                    # whole blocks of zeros
+        g[5 * block_w:6 * block_w] = 0.0
+        g[5 * block_w + 7 * i % block_w] = 1.5   # one entry: (0.0, 0) pads
+        g[6 * block_w] = 0.0                     # picks at lane 0 and not
+        grads[w] = g
+    calls = {"blocks": 0, "scatter": 0}
+
+    def count(kind, fn):
+        def wrapped(*a, **kw):
+            calls[kind] += 1
+            return fn(*a, **kw)
+        return wrapped
+
+    monkeypatch.setattr(rd, "add_in_blocks",
+                        count("blocks", rd.add_in_blocks))
+    monkeypatch.setattr(rd, "scatter_add_rows",
+                        count("scatter", rd.scatter_add_rows))
+    opt = adagrad(lr=0.05)
+    p0 = rng.randn(n).astype(np.float32)
+    red = MasterReducer({"w": jnp.asarray(p0)}, opt,
+                        GradientCompressor("blocktopk",
+                                           frac=kmax / block_w,
+                                           block_w=block_w))
+    flat, state = jnp.asarray(p0), opt.init(jnp.asarray(p0))
+    res = {w: jnp.zeros((n,), jnp.float32) for w in names}
+    counts = {w: float(2 + i) for i, w in enumerate(names)}
+    # (per-block keep of each worker, deferred workers) per step
+    plan = [({w: kmax >> (i % 3) for i, w in enumerate(names)}, set()),
+            ({w: kmax for w in names}, {names[1]}),
+            ({w: kmax >> ((i + 1) % 3) for i, w in enumerate(names)},
+             set())]
+    for step, (keep, defer) in enumerate(plan):
+        msgs = {w: ({"w": jnp.asarray(grads[w] * (step + 1))}, counts[w])
+                for w in names}
+        red.reduce_and_step(msgs, keep={w: rows * k for w, k in
+                                        keep.items()}, defer=defer)
+        k_arr = jnp.asarray(_pad_to_capacity(
+            [0 if w in defer else keep[w] for w in names], 0), jnp.int32)
+        ns = jnp.asarray(_pad_to_capacity(
+            [0.0 if w in defer else counts[w] for w in names], 0.0),
+            jnp.float32)
+        flat, state, new_res = _blocktopk_scatter_reference(
+            flat, state,
+            _pad_to_capacity([msgs[w][0]["w"] for w in names],
+                             jnp.zeros(n)),
+            _pad_to_capacity([res[w] for w in names], jnp.zeros(n)),
+            ns, k_arr, kmax=max(keep[w] for w in names if w not in defer),
+            block_w=block_w, opt=opt)
+        res = dict(zip(names, new_res))
+        for w in names:
+            np.testing.assert_array_equal(
+                np.asarray(red._residuals[w]).view(np.uint32),
+                np.asarray(res[w]).view(np.uint32))
+        np.testing.assert_array_max_ulp(np.asarray(red.flat_params),
+                                        np.asarray(flat), maxulp=1)
+        np.testing.assert_array_max_ulp(np.asarray(red.opt_state["accum"]),
+                                        np.asarray(state["accum"]),
+                                        maxulp=1)
+    # one trace (kmax is the same every step): a rebuild per worker for
+    # the residual and one for the segment-sum, or the residual's scatter
+    assert calls == ({"blocks": 2 * W_cap, "scatter": 0}
+                     if case == "in_blocks" else
+                     {"blocks": 0, "scatter": 1})

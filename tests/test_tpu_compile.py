@@ -82,10 +82,21 @@ def test_block_topk_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_reduce_step_scatters_keep_their_scopes(one_chip):
-    # the reducer's scope shares read each op's op_name: both scatters of
-    # the compiled blocktopk step (the compiler's custom fusions) and the
-    # sort of their indices must keep the named scope that asked for them
+# temp bytes of the blocktopk step below as two scatter-adds (and the sort
+# of their indices), compiled for the same described chip
+SCATTER_STEP_TEMP_BYTES = 20_103_680
+
+
+@pytest.mark.parametrize("method,kmax", [("blocktopk", 4), ("topk", 4096)])
+def test_reduce_step_scatters_keep_their_scopes(one_chip, method, kmax):
+    # the reducer's scope shares read each op's op_name. blocktopk
+    # rebuilds its residual and segment-sum block by block: no scatter
+    # and no sort, fusions under reduce/residual writing the residual
+    # rows, the segment-sum's compares under reduce/segment_sum inside
+    # the fusion that writes the parameters, and no more temporary bytes
+    # than the scatters took. topk's indices are global: both scatters
+    # (the compiler's custom fusions) and the sort of their indices keep
+    # the scope that asked for them
     import re
 
     from repro.core.compression import GradientCompressor
@@ -95,7 +106,7 @@ def test_reduce_step_scatters_keep_their_scopes(one_chip):
     leaves = {"a": (1024, 1024), "b": (4096,)}
     red = MasterReducer({k: jnp.ones(v) for k, v in leaves.items()},
                         get_optimizer("adagrad", lr=0.05),
-                        GradientCompressor("blocktopk", frac=4 / 128,
+                        GradientCompressor(method, frac=4 / 128,
                                            block_w=128))
 
     def S(shape, dt=jnp.float32):
@@ -103,14 +114,47 @@ def test_reduce_step_scatters_keep_their_scopes(one_chip):
 
     n = red.flat_n
     tree = {k: S(v) for k, v in leaves.items()}
-    text = red._build_step_fn(W, 4).lower(
+    compiled = red._build_step_fn(W, kmax).lower(
         S((n,)), jax.tree.map(lambda x: S(x.shape, x.dtype), red.opt_state),
         (tree,) * W, (S((n,)),) * W, S((W,)), S((), jnp.int32),
-        S((W,), jnp.int32)).compile().as_text()
+        S((W,), jnp.int32)).compile()
+    text = compiled.as_text()
     entry = text[text.index("\nENTRY"):].split("\n}")[0].splitlines()
-    heavy = [ln for ln in entry if " sort(" in ln
-             or (" fusion(" in ln and "kind=kCustom" in ln)]
-    assert len(heavy) == 3, heavy
-    for ln in heavy:
-        assert re.search(r'op_name="[^"]*/reduce/(residual|segment_sum)/',
-                         ln), ln[:160]
+    if method == "topk":
+        heavy = [ln for ln in entry if "scatter" in ln and (
+            " sort(" in ln or (" fusion(" in ln and "kind=kCustom" in ln))]
+        assert len(heavy) == 3, heavy
+        for ln in heavy:
+            assert re.search(
+                r'op_name="[^"]*/reduce/(residual|segment_sum)/', ln), \
+                ln[:160]
+        return
+
+    assert not re.search(r"\b(scatter|sort)\(", text)
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= SCATTER_STEP_TEMP_BYTES)
+    lines = {ln.split(" = ")[0].split()[-1]: ln for ln in entry
+             if " = " in ln}
+    root = next(ln for ln in entry if ln.lstrip().startswith("ROOT"))
+    outs = re.findall(r"%[\w.\-]+", root.split(" tuple(")[1])
+
+    def producer(name):
+        """The fusion that writes a ROOT operand (through its tuple)."""
+        ln = lines[name]
+        if " get-tuple-element(" in ln:
+            ln = lines[re.search(r"get-tuple-element\((%[\w.\-]+)",
+                                 ln).group(1)]
+        assert " fusion(" in ln, ln[:160]
+        return ln
+
+    def body(fusion_line):
+        comp = re.search(r"calls=(%[\w.\-]+)", fusion_line).group(1)
+        start = text.index("\n" + comp + " ")
+        return text[start:text.index("\n}", start)]
+
+    for name in outs[-W:]:                      # the residual rows
+        assert re.search(r'op_name="[^"]*/reduce/residual/',
+                         producer(name)), name
+    # the new parameters: the segment-sum rebuilt inside their fusion
+    assert re.search(r'op_name="[^"]*/reduce/segment_sum/[^"]*eq"',
+                     body(producer(outs[0])))
