@@ -3,6 +3,7 @@ from repro.configs import (  # noqa: F401
     arctic_480b,
     mlitb_lm_100m,
     command_r_plus_104b,
+    granite_4_0_h_micro,
     granite_8b,
     llama4_scout_17b_a16e,
     mamba2_780m,

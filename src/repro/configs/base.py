@@ -19,6 +19,10 @@ MOE = "moe"            # attention + MoE block
 SSM = "ssm"            # Mamba2 block (attention-free)
 HYBRID_ATTN = "hattn"  # shared attention block inside an SSM stack (Zamba2)
 
+# the published ``layer_types`` names (Granite-4.0-H) -> block kinds: each
+# such layer is a mixer (Mamba2 or attention) followed by a dense MLP
+LAYER_TYPES = {"mamba": SSM, "attention": ATTN}
+
 
 @dataclass(frozen=True)
 class MoEConfig:
@@ -75,6 +79,18 @@ class ArchConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     hybrid_attn_period: int = 0     # zamba2: every Nth block is shared attention
+    # per-layer mixer order as published ("mamba" | "attention"), each
+    # layer followed by a SwiGLU MLP (granitemoehybrid); () = not this kind
+    layer_types: Tuple[str, ...] = ()
+    # Granite's scalars (layer_types models only; the defaults are no-ops):
+    # x = embedding_multiplier * E[tok]; each branch adds
+    # residual_multiplier * f(RMSNorm(x)); the softmax scale is
+    # attention_multiplier (None: head_dim ** -0.5); logits are divided
+    # by logits_scaling
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # encoder-decoder (whisper backbone)
     enc_dec: bool = False
@@ -93,6 +109,26 @@ class ArchConfig:
     def __post_init__(self):
         if self.n_heads and not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        # a tuple, however given (a JSON round trip gives a list)
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            bad = set(self.layer_types) - set(LAYER_TYPES)
+            if bad or len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"{self.name}: layer_types must list n_layers="
+                    f"{self.n_layers} of {sorted(LAYER_TYPES)}")
+        elif (self.embedding_multiplier, self.attention_multiplier,
+              self.residual_multiplier, self.logits_scaling) \
+                != (1.0, None, 1.0, 1.0):
+            raise ValueError(f"{self.name}: Granite's multipliers apply to "
+                             f"layer_types models only")
+
+    @property
+    def attn_scale(self) -> float:
+        """The attention softmax scale."""
+        if self.attention_multiplier is not None:
+            return float(self.attention_multiplier)
+        return self.head_dim ** -0.5
 
     @property
     def attention_free(self) -> bool:
@@ -109,6 +145,8 @@ class ArchConfig:
 
     def block_pattern(self) -> Tuple[str, ...]:
         """Per-layer block kinds, length == n_layers (decoder stack)."""
+        if self.layer_types:
+            return tuple(LAYER_TYPES[t] for t in self.layer_types)
         if self.arch_type == "moe":
             return (MOE,) * self.n_layers
         if self.arch_type == "ssm":
@@ -148,12 +186,19 @@ class ArchConfig:
             nh = self.ssm.n_heads(d)
             g = self.ssm.n_groups
             n = d * (2 * di + 2 * g * self.ssm.d_state + nh)   # in_proj
-            n += self.ssm.d_conv * (di + 2 * g * self.ssm.d_state)  # conv
+            n += (self.ssm.d_conv + 1) * (di + 2 * g * self.ssm.d_state)
+            #    ^ depthwise conv weights and bias
             n += nh * 2 + nh                               # A_log, D, dt_bias
             n += di * d                                    # out_proj
             n += d + di                                    # pre-norm + gate norm
             return n
 
+        if self.layer_types:                              # mixer + MLP
+            for kind in self.block_pattern():
+                total += mlp_params(self.d_ff)
+                # attn_params counts both pre-norms; ssm_params its own
+                total += attn_params() if kind == ATTN else ssm_params() + d
+            return total
         for kind in self.block_pattern():
             if kind == ATTN:
                 total += attn_params() + mlp_params(self.d_ff)
@@ -216,6 +261,9 @@ class ArchConfig:
                 self.ssm, d_state=16, head_dim=32, chunk=16)
         if self.hybrid_attn_period:
             changes["hybrid_attn_period"] = 2
+            changes["n_layers"] = 4
+        if self.layer_types:
+            changes["layer_types"] = ("mamba", "mamba", "attention", "mamba")
             changes["n_layers"] = 4
         if self.enc_dec:
             changes.update(n_encoder_layers=2, encoder_seq=16)

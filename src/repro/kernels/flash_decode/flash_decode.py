@@ -114,14 +114,17 @@ def pages_walked(batch: int, page_blocks: int) -> int:
 def flash_decode_pallas(q: jnp.ndarray, kpool: jnp.ndarray,
                         vpool: jnp.ndarray, page_map: jnp.ndarray,
                         pos: jnp.ndarray, live: jnp.ndarray, *,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: bool = True,
+                        scale: float = None) -> jnp.ndarray:
     """q: (B,H,D); kpool/vpool: (N,K,ps,D); page_map: (B,P) int32 (>=N
-    means no page); pos/live: (B,) int32 -> (B,H,D)."""
+    means no page); pos/live: (B,) int32 -> (B,H,D). ``scale`` is the
+    softmax scale, ``D ** -0.5`` when not given."""
     B, H, D = q.shape
     N, K, ps, _ = kpool.shape
     P = page_map.shape[1]
     G = H // K
-    scale = D ** -0.5
+    if scale is None:
+        scale = D ** -0.5
 
     kernel = functools.partial(
         _flash_decode_kernel, page_size=ps, n_pages=N, n_page_blocks=P,

@@ -15,17 +15,19 @@ from repro.kernels.flash_decode.flash_decode import flash_decode_pallas
 from repro.kernels.runtime import default_interpret
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "scale"))
 def flash_decode(q: jnp.ndarray, kpool: jnp.ndarray, vpool: jnp.ndarray,
                  page_map: jnp.ndarray, pos: jnp.ndarray,
-                 live: jnp.ndarray, *, interpret: bool = None) -> jnp.ndarray:
+                 live: jnp.ndarray, *, interpret: bool = None,
+                 scale: float = None) -> jnp.ndarray:
     """q: (B,H,D); kpool/vpool: (N,K,ps,D); page_map: (B,P) int32 with
     entries >= N meaning 'no page'; pos: (B,) last valid position per
-    row; live: (B,) row mask -> (B,H,D)."""
+    row; live: (B,) row mask -> (B,H,D). ``scale``: the softmax scale
+    (static; ``D ** -0.5`` when not given)."""
     interpret = default_interpret(interpret)
     return flash_decode_pallas(
         q, kpool, vpool,
         page_map.astype(jnp.int32),
         pos.astype(jnp.int32),
         live.astype(jnp.int32),
-        interpret=interpret)
+        interpret=interpret, scale=scale)

@@ -15,10 +15,11 @@ NEG_INF = -1e30
 
 def flash_decode_ref(q: jnp.ndarray, kpool: jnp.ndarray, vpool: jnp.ndarray,
                      page_map: jnp.ndarray, pos: jnp.ndarray,
-                     live: jnp.ndarray) -> jnp.ndarray:
+                     live: jnp.ndarray, scale: float = None) -> jnp.ndarray:
     """q: (B,H,D); kpool/vpool: (N,K,ps,D); page_map: (B,P) int32 with
     entries >= N meaning 'no page'; pos: (B,) int32 last valid position
-    per row; live: (B,) int32/bool row mask -> (B,H,D)."""
+    per row; live: (B,) int32/bool row mask -> (B,H,D). ``scale``: the
+    softmax scale (``D ** -0.5`` when not given)."""
     B, H, D = q.shape
     N, K, ps, _ = kpool.shape
     P = page_map.shape[1]
@@ -33,7 +34,7 @@ def flash_decode_ref(q: jnp.ndarray, kpool: jnp.ndarray, vpool: jnp.ndarray,
         & (live.astype(jnp.int32) != 0)[:, None]
     qf = q.astype(jnp.float32).reshape(B, K, G, D)
     s = jnp.einsum("bkgd,btkd->bkgt", qf, k.astype(jnp.float32)) \
-        * (D ** -0.5)
+        * (D ** -0.5 if scale is None else scale)
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgt,btkd->bkgd", p, v.astype(jnp.float32))

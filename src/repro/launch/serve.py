@@ -174,10 +174,12 @@ def main(argv=None):
         params = jax.jit(tf.init_params, static_argnums=1)(
             jax.random.PRNGKey(args.seed), cfg)
 
-    if cfg.arch_type not in ("dense", "moe"):
-        # vlm/audio/ssm/hybrid: no slot-cache engine yet (ROADMAP
-        # follow-up) — serve one reference batch through serve_batch so
-        # every arch family the old launcher handled still serves
+    if cfg.arch_type not in ("dense", "moe") and not cfg.layer_types:
+        # vlm/audio/ssm and period hybrids: no slot-cache engine yet
+        # (ROADMAP follow-up) — serve one reference batch through
+        # serve_batch so every arch family the old launcher handled still
+        # serves. layer_types hybrids take the engine (paged, flash
+        # decode, --no-prefix-reuse)
         return _serve_oneshot(params, cfg, args)
 
     max_seq = args.max_seq

@@ -68,8 +68,10 @@ def _out_proj(p: Params, o: jnp.ndarray) -> jnp.ndarray:
 
 
 def grouped_attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                   mask: Optional[jnp.ndarray]) -> jnp.ndarray:
+                   mask: Optional[jnp.ndarray],
+                   scale: Optional[float] = None) -> jnp.ndarray:
     """q: (B,S,H,hd), k/v: (B,T,K,hd), mask broadcastable to (B,1,1,S,T).
+    ``scale`` is the softmax scale (None: ``hd ** -0.5``).
 
     Returns (B,S,H,hd). GQA via a group axis — no kv repetition in memory.
     """
@@ -82,7 +84,8 @@ def grouped_attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # all-gathers in the decode dry-run); preferred_element_type gets the
     # same numerics from the MXU without the copies.
     logits = jnp.einsum("bskgd,btkd->bkgst", qg, k,
-                        preferred_element_type=jnp.float32) * (hd ** -0.5)
+                        preferred_element_type=jnp.float32) \
+        * (hd ** -0.5 if scale is None else scale)
     if mask is not None:
         logits = jnp.where(mask, logits, NEG_INF)
     w = jax.nn.softmax(logits, axis=-1)
@@ -111,7 +114,8 @@ def attention(p: Params, x: jnp.ndarray, positions: jnp.ndarray, *,
               causal: bool = True, window: int = 0, use_rope: bool = True,
               rope_theta: float = 10000.0,
               xkv: Optional[jnp.ndarray] = None,
-              kv_positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+              kv_positions: Optional[jnp.ndarray] = None,
+              scale: Optional[float] = None) -> jnp.ndarray:
     """Self-attention (xkv=None) or cross-attention (xkv=encoder states)."""
     xkv = x if xkv is None else xkv
     kv_positions = positions if kv_positions is None else kv_positions
@@ -121,7 +125,7 @@ def attention(p: Params, x: jnp.ndarray, positions: jnp.ndarray, *,
         k = apply_rope(k, kv_positions, rope_theta)
     mask = make_mask(positions, kv_positions, causal, window) \
         if (causal or window) else None
-    o = grouped_attend(q, k, v, mask)
+    o = grouped_attend(q, k, v, mask, scale)
     return _out_proj(p, o)
 
 
@@ -307,7 +311,8 @@ def attention_decode_ragged_paged_flash(p: Params, x: jnp.ndarray,
                                         live: jnp.ndarray,
                                         rmap: jnp.ndarray, wmap: jnp.ndarray,
                                         use_rope: bool = True,
-                                        rope_theta: float = 10000.0
+                                        rope_theta: float = 10000.0,
+                                        scale: Optional[float] = None
                                         ) -> Tuple[jnp.ndarray,
                                                    Tuple[jnp.ndarray,
                                                          jnp.ndarray]]:
@@ -321,7 +326,8 @@ def attention_decode_ragged_paged_flash(p: Params, x: jnp.ndarray,
     The new token's k/v lands on exactly one (page, offset) cell via the
     write map — equivalent to the gather -> oracle-write -> scatter
     composition because every non-frozen page is uniquely owned and the
-    scatter-back of unchanged pages is the identity. Returns
+    scatter-back of unchanged pages is the identity. ``scale`` is the
+    softmax scale (None: the kernel's ``hd ** -0.5``). Returns
     ``(out (B,1,d), (new kbuf, new vbuf))``."""
     from repro.kernels.flash_decode import flash_decode
     ps = kbuf.shape[2]
@@ -337,14 +343,16 @@ def attention_decode_ragged_paged_flash(p: Params, x: jnp.ndarray,
     nk = kbuf.at[wpage, :, woff].set(k[:, 0].astype(kbuf.dtype))
     nv = vbuf.at[wpage, :, woff].set(v[:, 0].astype(vbuf.dtype))
     qpos = jnp.where(live, posb[:, 0], 0).astype(jnp.int32)
-    o = flash_decode(q[:, 0], nk, nv, rmap, qpos, live.astype(jnp.int32))
+    o = flash_decode(q[:, 0], nk, nv, rmap, qpos, live.astype(jnp.int32),
+                     scale=scale)
     return _out_proj(p, o.astype(q.dtype)[:, None]), (nk, nv)
 
 
 def attention_prefill_chunk(p: Params, x: jnp.ndarray, off: jnp.ndarray,
                             clen: jnp.ndarray, *, cache: Params,
                             use_rope: bool = True,
-                            rope_theta: float = 10000.0
+                            rope_theta: float = 10000.0,
+                            scale: Optional[float] = None
                             ) -> Tuple[jnp.ndarray, Params]:
     """One CHUNK of a chunked ragged prefill — the serving engine's path
     for prompts longer than its largest prefill bucket (docs/serving.md).
@@ -379,7 +387,7 @@ def attention_prefill_chunk(p: Params, x: jnp.ndarray, off: jnp.ndarray,
     t = jnp.arange(T, dtype=jnp.int32)
     lim = jnp.where(valid_q, qpos, 0)                        # (B,C)
     mask = (t[None, None, :] <= lim[..., None])[:, None, None, :, :]
-    o = grouped_attend(q, ck, cv, mask)
+    o = grouped_attend(q, ck, cv, mask, scale)
     return _out_proj(p, o), {"k": ck, "v": cv}
 
 

@@ -14,7 +14,7 @@ x,B,C pass through a causal depthwise conv (width d_conv) + SiLU.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -228,9 +228,54 @@ def ssm_prefill(p: Params, x: jnp.ndarray, d: int, cfg: SSMConfig
     return y @ p["out_proj"], {"h": h, "conv": conv_tail}
 
 
+def ssm_prefill_chunk(p: Params, x: jnp.ndarray, clen: jnp.ndarray,
+                      state: Params, d: int, cfg: SSMConfig
+                      ) -> Tuple[jnp.ndarray, Params]:
+    """One chunk of a chunked ragged prefill, from each row's carried
+    state. x: (B,C,d), row b's valid tokens ``x[b, :clen_b]`` continuing
+    the sequence whose state is ``state`` ({h (B,nh,hd,N) f32, conv
+    (B,W-1,conv_dim)}: the last W-1 conv inputs, zero for a fresh row).
+
+    The conv runs over the carried ring followed by the chunk; the SSD
+    starts from ``h``. Padding columns (``t >= clen_b``) get ``dt = 0``
+    (no decay, no input), so the returned state is the state after
+    column ``clen_b - 1``, and the returned ring is the last W-1 conv
+    inputs up to that column; a row with ``clen_b == 0`` keeps its
+    state. Returns (y (B,C,d), new state)."""
+    C = x.shape[1]
+    W = cfg.d_conv
+    proj = x @ p["in_proj"]
+    _, xc, _ = _split_proj(proj, d, cfg)
+    hist = jnp.concatenate([state["conv"].astype(xc.dtype), xc], axis=1)
+    conv_out = sum(hist[:, i:i + C, :] * p["conv_w"][i]
+                   for i in range(W)) + p["conv_b"]
+    z, xs, Bm, Cm, dt, A = _pre_core(p, proj, conv_out, d, cfg)
+    valid = jnp.arange(C)[None, :] < clen[:, None]
+    dt = jnp.where(valid[..., None], dt, 0.0)
+    y, h = ssd_chunked(xs, dt, A, Bm, Cm, chunk=min(cfg.chunk, C),
+                       h0=state["h"])
+    y = y + p["D"][:, None] * xs.astype(jnp.float32)
+    y = y.reshape(*x.shape[:-1], -1).astype(x.dtype)
+    y = rmsnorm(p["gate_norm"], y * jax.nn.silu(z))
+    ring = jax.vmap(lambda hr, c: jax.lax.dynamic_slice_in_dim(
+        hr, c, W - 1, axis=0))(hist, clen.astype(jnp.int32))
+    return y @ p["out_proj"], {"h": h, "conv": ring.astype(
+        state["conv"].dtype)}
+
+
+def keep_live(live: jnp.ndarray, new: Params, old: Params) -> Params:
+    """``new`` state on live rows, ``old`` on the rest (B leading)."""
+    def pick(n, o):
+        m = live.reshape(live.shape + (1,) * (n.ndim - 1))
+        return jnp.where(m, n, o)
+    return jax.tree.map(pick, new, old)
+
+
 def ssm_decode(p: Params, x: jnp.ndarray, state: Params, d: int,
-               cfg: SSMConfig) -> Tuple[jnp.ndarray, Params]:
-    """One-token decode. x: (B,1,d); state: {h (B,nh,hd,N), conv (B,W-1,C)}."""
+               cfg: SSMConfig, live: Optional[jnp.ndarray] = None
+               ) -> Tuple[jnp.ndarray, Params]:
+    """One-token decode. x: (B,1,d); state: {h (B,nh,hd,N), conv (B,W-1,C)}.
+    With ``live`` (B,) bool, rows not live keep their state."""
     proj = x @ p["in_proj"]                                 # (B,1,P)
     _, xc, _ = _split_proj(proj, d, cfg)                    # (B,1,C)
     hist = jnp.concatenate([state["conv"], xc], axis=1)     # (B,W,C)
@@ -247,4 +292,6 @@ def ssm_decode(p: Params, x: jnp.ndarray, state: Params, d: int,
     y = y.reshape(x.shape[0], 1, -1).astype(x.dtype)
     y = rmsnorm(p["gate_norm"], y * jax.nn.silu(z))
     new_state = {"h": h, "conv": hist[:, 1:, :]}
+    if live is not None:
+        new_state = keep_live(live, new_state, state)
     return y @ p["out_proj"], new_state

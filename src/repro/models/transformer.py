@@ -6,6 +6,8 @@ Param layout (uniform stacks carry a leading L axis, consumed by lax.scan):
   ssm           : {embed, blocks, final_norm}
   hybrid        : {embed, super: {ssm (Ns,P-1,...), attn (Ns,...)},
                    tail (Nt,...), final_norm}
+  layer_types   : {embed, blocks: {mamba (Nm,...), attn (Na,...)},
+                   final_norm}: layer i is the next of its kind's stack
   audio(encdec) : {embed, encoder, enc_final_norm, blocks(dec), final_norm}
 
 Decode "cache" trees mirror the block structure with leading L axes.
@@ -13,7 +15,7 @@ Decode "cache" trees mirror the block structure with leading L axes.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -129,6 +131,18 @@ def init_decoder_xblock(key, cfg: ArchConfig, dtype) -> Params:
     }
 
 
+def init_mamba_layer(key, cfg: ArchConfig, dtype) -> Params:
+    """A ``layer_types`` Mamba2 layer: the mixer and a dense MLP, each
+    behind its own pre-norm."""
+    k1, k2 = jax.random.split(key)
+    return {
+        "ln1": init_rmsnorm(cfg.d_model, dtype),
+        "ssm": ssm_mod.init_ssm(k1, cfg.d_model, cfg.ssm, dtype),
+        "ln2": init_rmsnorm(cfg.d_model, dtype),
+        "mlp": init_mlp(k2, cfg.d_model, cfg.d_ff, cfg.mlp_act, dtype=dtype),
+    }
+
+
 def _stack_init(init_fn, key, n: int):
     return jax.vmap(init_fn)(jax.random.split(key, n))
 
@@ -145,7 +159,14 @@ def init_params(key, cfg: ArchConfig) -> Params:
         p["lm_head"] = init_embed(keys[1], cfg.vocab_size, cfg.d_model, dtype)
 
     cfg.block_pattern()          # validates the arch family eagerly
-    if cfg.arch_type in ("dense", "vlm"):
+    if cfg.layer_types:
+        counts = layer_counts(cfg)
+        inits = {MAMBA: init_mamba_layer, ATTENTION: init_attn_block}
+        p["blocks"] = {
+            STACK[t]: _stack_init(lambda k, f=inits[t]: f(k, cfg, dtype),
+                                  jax.random.fold_in(keys[2], i), counts[t])
+            for i, t in enumerate((MAMBA, ATTENTION)) if counts[t]}
+    elif cfg.arch_type in ("dense", "vlm"):
         p["blocks"] = _stack_init(lambda k: init_attn_block(k, cfg, dtype),
                                   keys[2], cfg.n_layers)
     elif cfg.arch_type == "moe":
@@ -222,6 +243,236 @@ def apply_ssm_block(bp: Params, x, cfg: ArchConfig) -> jnp.ndarray:
         x + ssm_mod.ssm_forward(bp["ssm"], h, cfg.d_model, cfg.ssm))
 
 
+def _head(params: Params, cfg: ArchConfig, x) -> jnp.ndarray:
+    """Final norm and the output head: fp32 logits (divided by Granite's
+    ``logits_scaling`` where it is set)."""
+    x = apply_norm(params["final_norm"], x, cfg.norm_eps)
+    head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+    logits = unembed(head, x)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
+
+
+# ---------------------------------------------------------------------------
+# Per-layer hybrids (``cfg.layer_types``, Granite-4.0-H): every layer is a
+# Mamba2 or an attention mixer, then a dense MLP, each branch scaled by
+# ``residual_multiplier``. Each kind's layers form one stack; a run of
+# same-kind Mamba layers is one scan, the (few) attention layers are
+# unrolled so each owns its KV page buffers.
+# ---------------------------------------------------------------------------
+MAMBA, ATTENTION = "mamba", "attention"
+STACK = {MAMBA: "mamba", ATTENTION: "attn"}
+
+
+def layer_counts(cfg: ArchConfig) -> Dict[str, int]:
+    return {t: sum(1 for x in cfg.layer_types if x == t)
+            for t in (MAMBA, ATTENTION)}
+
+
+def layer_runs(cfg: ArchConfig) -> List[Tuple[str, int, int]]:
+    """Runs of same-kind layers in order: (kind, index of the run's first
+    layer in its kind's stack, length)."""
+    runs: List[Tuple[str, int, int]] = []
+    seen = {MAMBA: 0, ATTENTION: 0}
+    for t in cfg.layer_types:
+        if runs and runs[-1][0] == t:
+            runs[-1] = (t, runs[-1][1], runs[-1][2] + 1)
+        else:
+            runs.append((t, seen[t], 1))
+        seen[t] += 1
+    return runs
+
+
+def _layer(stack: Params, i) -> Params:
+    """Layer ``i`` (static or traced) of a stacked tree."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+        stack)
+
+
+def _residual(cfg: ArchConfig, x, y):
+    return x + jnp.asarray(cfg.residual_multiplier, x.dtype) * y.astype(
+        x.dtype)
+
+
+def _mlp_branch(lp: Params, x, cfg: ArchConfig):
+    h = apply_norm(lp["ln2"], x, cfg.norm_eps)
+    return _residual(cfg, x, mlp(lp["mlp"], h, cfg.mlp_act))
+
+
+def _hybrid_embed(params: Params, cfg: ArchConfig, tokens) -> jnp.ndarray:
+    adt = dtype_of(cfg.activ_dtype)
+    x = embed(params["embed"], tokens).astype(adt)
+    return x * jnp.asarray(cfg.embedding_multiplier, adt)
+
+
+def hybrid_forward(params: Params, cfg: ArchConfig, x, positions, scan,
+                   maybe_ckpt) -> jnp.ndarray:
+    """The training path's layers over embedded ``x`` (B,S,d)."""
+    blocks = params["blocks"]
+    for kind, start, n in layer_runs(cfg):
+        if kind == MAMBA:
+            @maybe_ckpt
+            def body(h, i):
+                lp = _layer(blocks["mamba"], i)
+                hh = apply_norm(lp["ln1"], h, cfg.norm_eps)
+                h = _residual(cfg, h, ssm_mod.ssm_forward(
+                    lp["ssm"], hh, cfg.d_model, cfg.ssm))
+                return constrain_batch(_mlp_branch(lp, h, cfg)), None
+            x, _ = scan(body, x, jnp.arange(start, start + n))
+            continue
+        for j in range(start, start + n):
+            lp = _layer(blocks["attn"], j)
+            hh = apply_norm(lp["ln1"], x, cfg.norm_eps)
+            x = _residual(cfg, x, attn_mod.attention(
+                lp["attn"], hh, positions, causal=True,
+                use_rope=cfg.use_rope, rope_theta=cfg.rope_theta,
+                scale=cfg.attn_scale))
+            x = constrain_batch(_mlp_branch(lp, x, cfg))
+    return x
+
+
+def hybrid_cache(cfg: ArchConfig, slots: int, n_pages: int,
+                 page_size: int) -> Params:
+    """The serving engine's cache for a ``layer_types`` model: one page
+    buffer pair ``(n_pages, n_kv, page_size, head_dim)`` per attention
+    layer, and a state pool by slot for each run of Mamba layers: ``h``
+    (n, slots, nh, hd, N) float32 and the conv ring (n, slots, W-1,
+    conv_dim) in the activation dtype, ``n`` the run's length. (One pool
+    a run, so that each buffer passes through one loop only: a pool
+    carried through several loops with attention between them is copied
+    by the compiler.) Zeros."""
+    adt = dtype_of(cfg.activ_dtype)
+    shape = (n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
+    kv = [{"k": jnp.zeros(shape, adt), "v": jnp.zeros(shape, adt)}
+          for _ in range(layer_counts(cfg)[ATTENTION])]
+    spec = ssm_mod.ssm_states_spec(slots, cfg.d_model, cfg.ssm, adt)
+    state = [{k: jnp.zeros((n,) + v.shape, v.dtype) for k, v in spec.items()}
+             for kind, _, n in layer_runs(cfg) if kind == MAMBA]
+    return {"kv": kv, "state": state}
+
+
+def reset_slot_state(state: Params, slots: jnp.ndarray) -> Params:
+    """Zero the state of ``slots`` (entries past the pool are dropped)."""
+    return jax.tree.map(lambda v: v.at[:, slots].set(0, mode="drop"), state)
+
+
+def hybrid_prefill_chunk_paged(params: Params, cfg: ArchConfig,
+                               tokens: jnp.ndarray, off: jnp.ndarray,
+                               clen: jnp.ndarray, cache: Params,
+                               rmap: jnp.ndarray, wmap: jnp.ndarray,
+                               slots: jnp.ndarray, unroll: bool = False
+                               ) -> Tuple[jnp.ndarray, Params]:
+    """One chunk of a chunked ragged prefill of a ``layer_types`` model
+    (the shapes of ``prefill_chunk_paged``; ``slots`` (B,) is each row's
+    slot, the pool size for padding rows). A Mamba layer gathers its
+    rows' carried state, runs ``ssm_prefill_chunk`` and writes the state
+    at each row's last valid column back in place; an attention layer
+    runs ``prefill_chunk``'s math on its page buffers. Returns the
+    logits at each row's last valid column (B,1,V) and the cache."""
+    scan = functools.partial(scan_apply, unroll=unroll)
+    blocks = params["blocks"]
+    x = _hybrid_embed(params, cfg, tokens)
+    state, kv = list(cache["state"]), list(cache["kv"])
+    rows = jnp.clip(slots, 0, state[0]["h"].shape[1] - 1)
+    runs = [r for r in layer_runs(cfg) if r[0] == MAMBA]
+    for kind, start, n in layer_runs(cfg):
+        if kind == MAMBA:
+            def body(carry, i):
+                h, st = carry
+                lp = _layer(blocks["mamba"], start + i)
+                with jax.named_scope("serve/ssm"):
+                    mine = {k: _layer(v, i)[rows] for k, v in st.items()}
+                    y, new = ssm_mod.ssm_prefill_chunk(
+                        lp["ssm"], apply_norm(lp["ln1"], h, cfg.norm_eps),
+                        clen, mine, cfg.d_model, cfg.ssm)
+                    h = _residual(cfg, h, y)
+                    st = {k: v.at[i, slots].set(new[k].astype(v.dtype),
+                                                mode="drop")
+                          for k, v in st.items()}
+                with jax.named_scope("serve/mlp"):
+                    h = _mlp_branch(lp, h, cfg)
+                return (h, st), None
+            r = runs.index((kind, start, n))
+            (x, state[r]), _ = scan(body, (x, state[r]), jnp.arange(n))
+            continue
+        for j in range(start, start + n):
+            lp = _layer(blocks["attn"], j)
+            # one layer's page buffers relaid out at a time (see the decode)
+            x, kv[j] = jax.lax.optimization_barrier((x, kv[j]))
+            with jax.named_scope("serve/attn"):
+                view = {m: gather_kv_pages(kv[j][m][None], rmap)[0]
+                        for m in ("k", "v")}
+                a, view = attn_mod.attention_prefill_chunk(
+                    lp["attn"], apply_norm(lp["ln1"], x, cfg.norm_eps),
+                    off, clen, cache=view, use_rope=cfg.use_rope,
+                    rope_theta=cfg.rope_theta, scale=cfg.attn_scale)
+                x = _residual(cfg, x, a)
+                kv[j] = {m: scatter_kv_pages(kv[j][m][None], wmap,
+                                             view[m][None])[0]
+                         for m in ("k", "v")}
+            with jax.named_scope("serve/mlp"):
+                x = _mlp_branch(lp, x, cfg)
+    idx = jnp.clip(clen.astype(jnp.int32) - 1, 0, x.shape[1] - 1)
+    x = jnp.take_along_axis(x, idx[:, None, None], axis=1)
+    return _head(params, cfg, x), {"kv": kv, "state": state}
+
+
+def hybrid_decode_paged_flash(params: Params, cfg: ArchConfig,
+                              token: jnp.ndarray, pos: jnp.ndarray,
+                              cache: Params, live: jnp.ndarray,
+                              rmap: jnp.ndarray, wmap: jnp.ndarray,
+                              unroll: bool = False
+                              ) -> Tuple[jnp.ndarray, Params]:
+    """One-token ragged decode of a ``layer_types`` model over every slot
+    (row == slot): each Mamba layer reads and writes the whole state pool
+    in place and changes only ``live`` rows; each attention layer runs
+    the paged flash-decode kernel on its page buffers."""
+    scan = functools.partial(scan_apply, unroll=unroll)
+    blocks = params["blocks"]
+    x = _hybrid_embed(params, cfg, token)
+    state, kv = list(cache["state"]), list(cache["kv"])
+    runs = [r for r in layer_runs(cfg) if r[0] == MAMBA]
+    for kind, start, n in layer_runs(cfg):
+        if kind == MAMBA:
+            def body(carry, i):
+                h, st = carry
+                lp = _layer(blocks["mamba"], start + i)
+                with jax.named_scope("serve/ssm"):
+                    y, new = ssm_mod.ssm_decode(
+                        lp["ssm"], apply_norm(lp["ln1"], h, cfg.norm_eps),
+                        _layer(st, i), cfg.d_model, cfg.ssm, live=live)
+                    h = _residual(cfg, h, y)
+                    st = {k: jax.lax.dynamic_update_index_in_dim(
+                        v, new[k].astype(v.dtype), i, 0)
+                        for k, v in st.items()}
+                with jax.named_scope("serve/mlp"):
+                    h = _mlp_branch(lp, h, cfg)
+                return (h, st), None
+            r = runs.index((kind, start, n))
+            (x, state[r]), _ = scan(body, (x, state[r]), jnp.arange(n))
+            continue
+        for j in range(start, start + n):
+            lp = _layer(blocks["attn"], j)
+            # tie the layer's page buffers to its input: the compiler
+            # relays the buffers out for the kernel (head_dim 64 pages are
+            # not stored row-major), and would otherwise relay every
+            # layer's at once, before the first layer runs
+            x, kv[j] = jax.lax.optimization_barrier((x, kv[j]))
+            with jax.named_scope("serve/attn"):
+                a, (nk, nv) = attn_mod.attention_decode_ragged_paged_flash(
+                    lp["attn"], apply_norm(lp["ln1"], x, cfg.norm_eps), pos,
+                    kbuf=kv[j]["k"], vbuf=kv[j]["v"], live=live, rmap=rmap,
+                    wmap=wmap, use_rope=cfg.use_rope,
+                    rope_theta=cfg.rope_theta, scale=cfg.attn_scale)
+                x = _residual(cfg, x, a)
+                kv[j] = {"k": nk, "v": nv}
+            with jax.named_scope("serve/mlp"):
+                x = _mlp_branch(lp, x, cfg)
+    return _head(params, cfg, x), {"kv": kv, "state": state}
+
+
 # ---------------------------------------------------------------------------
 # Full forward (training path) -> (logits, aux_loss)
 # ---------------------------------------------------------------------------
@@ -296,6 +547,10 @@ def forward(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
             return apply_ssm_block(bp, h, cfg), None
         x, _ = scan(body, x, params["blocks"])
 
+    elif cfg.layer_types:
+        x = hybrid_forward(params, cfg, x * jnp.asarray(
+            cfg.embedding_multiplier, adt), positions, scan, maybe_ckpt)
+
     elif cfg.arch_type == "hybrid":
         @maybe_ckpt
         def super_body(h, sp):
@@ -312,12 +567,9 @@ def forward(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
     else:
         raise ValueError(cfg.arch_type)
 
-    x = apply_norm(params["final_norm"], x, cfg.norm_eps)
     if cfg.arch_type == "vlm":                               # loss on text only
         x = x[:, -tokens.shape[1]:, :]
-    head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    logits = unembed(head, x)
-    return logits, aux_total
+    return _head(params, cfg, x), aux_total
 
 
 # ---------------------------------------------------------------------------
@@ -327,6 +579,9 @@ def init_decode_cache(cfg: ArchConfig, batch: int, cache_len: int,
                       abstract: bool = False) -> Params:
     """Cache tree with leading per-stack L axes. ``cache_len`` is the KV
     length; sliding-window archs get a ring of min(window, cache_len)."""
+    if cfg.layer_types:
+        raise ValueError(f"{cfg.name}: layer_types models keep their cache "
+                         f"in the serving engine (hybrid_cache)")
     adt = dtype_of(cfg.activ_dtype)
     eff = min(cfg.sliding_window, cache_len) if cfg.sliding_window else cache_len
     sd = jax.ShapeDtypeStruct
@@ -374,6 +629,9 @@ def decode_step(params: Params, cfg: ArchConfig, token: jnp.ndarray,
     """ONE-token decode. token: (B,1) int32; pos: scalar int32 (same for all
     rows — continuous batching with per-row positions is a serving-layer
     concern handled by repro.serve). Returns (logits (B,1,V), new cache)."""
+    if cfg.layer_types:
+        raise ValueError(f"{cfg.name}: layer_types models decode through "
+                         f"decode_step_ragged_paged_flash")
     scan = functools.partial(scan_apply, unroll=unroll)
     adt = dtype_of(cfg.activ_dtype)
     x = embed(params["embed"], token).astype(adt)
@@ -612,14 +870,22 @@ def prefill_chunk_paged(params: Params, cfg: ArchConfig,
                         tokens: jnp.ndarray, off: jnp.ndarray,
                         clen: jnp.ndarray, pool: Params,
                         rmap: jnp.ndarray, wmap: jnp.ndarray,
-                        unroll: bool = False, all_logits: bool = False
+                        unroll: bool = False, all_logits: bool = False,
+                        slots: Optional[jnp.ndarray] = None
                         ) -> Tuple[jnp.ndarray, Params]:
     """``prefill_chunk`` through a page table: gather each row's pages
     into a linear view (``rmap``), run the IDENTICAL chunk math, scatter
     the updated view back through ``wmap`` (frozen/shared/padding
     entries OOB -> dropped). With ``P*page_size == max_seq`` the inner
     program is the same as the dense engine's, so outputs are bit-exact
-    vs the dense slot cache (tests/test_paging.py)."""
+    vs the dense slot cache (tests/test_paging.py). A ``layer_types``
+    model (``hybrid_prefill_chunk_paged``) also takes each row's
+    ``slots``, whose carried state its Mamba layers continue."""
+    if cfg.layer_types:
+        assert not all_logits, "a recurrent state cannot verify drafts"
+        return hybrid_prefill_chunk_paged(params, cfg, tokens, off, clen,
+                                          pool, rmap, wmap, slots,
+                                          unroll=unroll)
     view = {"layers": {n: gather_kv_pages(pool["layers"][n], rmap)
                        for n in ("k", "v")}}
     logits, view = prefill_chunk(params, cfg, tokens, off, clen, view,
@@ -658,7 +924,11 @@ def decode_step_ragged_paged_flash(params: Params, cfg: ArchConfig,
     of ``decode_step_ragged_paged`` disappears entirely: each layer's
     attention dereferences ``rmap`` inside the kernel and the new
     token's KV lands on one (page, offset) cell through ``wmap``. Same
-    trace-shape contract (fixed ``(B, P)`` maps -> single trace)."""
+    trace-shape contract (fixed ``(B, P)`` maps -> single trace). A
+    ``layer_types`` model runs ``hybrid_decode_paged_flash``."""
+    if cfg.layer_types:
+        return hybrid_decode_paged_flash(params, cfg, token, pos, pool, live,
+                                         rmap, wmap, unroll=unroll)
     assert cfg.arch_type in ("dense", "vlm", "moe"), \
         f"ragged decode needs an attention cache, not {cfg.arch_type}"
     scan = functools.partial(scan_apply, unroll=unroll)

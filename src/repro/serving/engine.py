@@ -53,6 +53,15 @@ top-k sampling with a per-request PRNG key folded per generated token —
 the key depends only on (engine seed, request id, token index), so a
 request's stream is deterministic and independent of co-batching.
 
+**Recurrent state beside pages** (docs/serving.md §11): a model with
+``layer_types`` (Mamba2 and attention layers, Granite-4.0-H) keeps a
+state pool by slot beside a KV pool that holds only its attention
+layers. Admission zeroes the slot's state; each prefill chunk continues
+it from where the previous chunk left it, and decode changes only the
+rows it serves. It runs paged with the flash-decode kernel only, and
+without prefix reuse or speculative decoding: pages alone cannot restore
+a state, and a state cannot roll back rejected drafts.
+
 Slot invariant: cache row ``s`` is valid exactly on ``[0, pos_s]`` and
 decode at position ``p`` overwrites index ``p`` before attending to it,
 so freed rows never need scrubbing and a slot's previous occupant can
@@ -81,6 +90,7 @@ from repro.configs.base import ArchConfig
 from repro.core import obs
 from repro.kernels.flash_decode import pages_walked
 from repro.models.layers import dtype_of
+from repro.models.transformer import hybrid_cache, reset_slot_state
 from repro.serving.config import (BackpressureConfig, PagingConfig,
                                   SamplingConfig, ServingConfig,
                                   SpeculativeConfig)
@@ -90,6 +100,13 @@ from repro.train.step import build_draft_program, build_serve_programs
 PyTree = Any
 
 NEG_INF = -1e30
+# Most rows one prefill-chunk dispatch of a layer_types model feeds, the
+# oldest admissions first (the rest wait a step). A prefill chunk reads
+# and writes its rows' state on top of the whole state pool, so its
+# memory grows with the rows: at Granite-4.0-H-Micro's widths with 48
+# slots the compiler puts a 4 x 512 chunk at 13.92 GB and 8 x 512 at
+# 16.07 GB, more than a 16 GB chip holds. It also bounds the buckets.
+HYBRID_PREFILL_ROWS = 4
 
 
 def pow2_bucket(n: int, lo: int = 1, hi: Optional[int] = None) -> int:
@@ -220,6 +237,7 @@ class _SlotState:
     inserted: int = 0               # paged: prompt pages published so far
     admitted: Optional[float] = None     # clocks for the Completion
     first_token: Optional[float] = None
+    seq: int = 0                    # admission order
 
 
 class ServingEngine:
@@ -232,10 +250,26 @@ class ServingEngine:
         # the flat-kwarg constructor completed its one deprecation cycle
         # and is gone — ``ServingConfig.from_flat(...)`` remains as the
         # kwargs-shaped builder for callers migrating mechanically
-        if cfg.arch_type not in ("dense", "moe"):
+        self.hybrid = bool(cfg.layer_types)
+        if cfg.arch_type not in ("dense", "moe") and not self.hybrid:
             raise ValueError(
                 f"ServingEngine supports attention-cached LM archs "
-                f"(dense/moe), not {cfg.arch_type!r}")
+                f"(dense/moe) and layer_types hybrids, not "
+                f"{cfg.arch_type!r}")
+        if self.hybrid:
+            # the layout and the decode kernel are build_serve_programs'
+            # to refuse; these two are the engine's
+            if serving.paging is not None and serving.paging.prefix_reuse:
+                raise ValueError(
+                    f"{cfg.name}: prefix_reuse cannot serve recurrent "
+                    f"(Mamba) layers: shared pages restore attention KV, "
+                    f"not the state after the prefix; set "
+                    f"PagingConfig(prefix_reuse=False)")
+            if serving.speculative is not None:
+                raise ValueError(
+                    f"{cfg.name}: speculative decoding cannot serve "
+                    f"recurrent (Mamba) layers: their state cannot roll "
+                    f"back the rejected drafts")
         if cfg.sliding_window and serving.max_seq > cfg.sliding_window:
             raise ValueError(
                 f"max_seq={serving.max_seq} exceeds sliding_window="
@@ -262,6 +296,7 @@ class ServingEngine:
         self.max_seq = int(serving.max_seq)
         self.prompt_bucket_min = int(serving.prompt_bucket_min)
         self.prompt_cap = serving.resolved_prompt_cap
+        self.prefill_rows = HYBRID_PREFILL_ROWS if self.hybrid else None
         self._temperature = float(serving.sampling.temperature)
         self._top_k = int(serving.sampling.top_k)
         self._sample_seed = int(serving.sampling.sample_seed)
@@ -329,8 +364,16 @@ class ServingEngine:
         self._programs = build_serve_programs(
             cfg, paged=self.paged, unroll=self._unroll,
             decode_kernel=self.decode_kernel)
-        self.cache: PyTree = {"layers": {"k": jnp.zeros(shape, adt),
-                                         "v": jnp.zeros(shape, adt)}}
+        if self.hybrid:
+            # the KV pool holds the attention layers only; the Mamba
+            # layers' state lives in a pool by slot beside it
+            self.cache: PyTree = hybrid_cache(cfg, self.max_batch,
+                                              self.n_pages, self.page_size)
+        else:
+            self.cache = {"layers": {"k": jnp.zeros(shape, adt),
+                                     "v": jnp.zeros(shape, adt)}}
+        self._reset_fn = None
+        self._admit_seq = 0
         self._slots: List[Optional[_SlotState]] = [None] * self.max_batch
         self._pos = np.zeros(self.max_batch, np.int32)
         self._tok = np.zeros(self.max_batch, np.int32)
@@ -555,15 +598,15 @@ class ServingEngine:
             pstep = self._programs.prefill_chunk
 
             def chunk_paged(params, tokens, off, clen, rids, rmap, wmap,
-                            pool):
+                            slots, pool):
                 self._trace_count += 1      # trace-time only side effect
                 logits, pool = pstep(params, tokens, off, clen, pool,
-                                     rmap, wmap)
+                                     rmap, wmap, slots)
                 nxt = self._sample(logits[:, -1, :], rids,
                                    jnp.zeros_like(rids))
                 return nxt, pool
 
-            fn = jax.jit(chunk_paged, donate_argnums=(7,))
+            fn = jax.jit(chunk_paged, donate_argnums=(8,))
             self._chunk_fns[(bcap, ccap)] = fn
             return fn
         cstep = self._programs.prefill_chunk
@@ -620,6 +663,19 @@ class ServingEngine:
 
         self._decode_fn = jax.jit(decode_all_slots, donate_argnums=(4,))
         return self._decode_fn
+
+    def _reset_states(self, slots: List[int]) -> None:
+        """Zero the recurrent state of the newly admitted ``slots`` (one
+        fixed-shape dispatch, padded with out-of-pool slots)."""
+        if self._reset_fn is None:
+            def reset(state, idx):
+                self._trace_count += 1      # trace-time only side effect
+                return reset_slot_state(state, idx)
+            self._reset_fn = jax.jit(reset, donate_argnums=(0,))
+        idx = np.full(self.max_batch, self.max_batch, np.int32)
+        idx[:len(slots)] = slots
+        self.cache = dict(self.cache, state=self._reset_fn(
+            self.cache["state"], jnp.asarray(idx)))
 
     def _get_verify_fn(self, vcap: int):
         """Speculative VERIFY dispatch for one ``(max_batch, vcap)``
@@ -783,10 +839,17 @@ class ServingEngine:
                 if self._slots[s] is not None
                 and self._slots[s].filled < len(self._slots[s].req.prompt)]
         for ver in sorted({self._slots[s].ver for s in todo}):
+            group = [s for s in todo if self._slots[s].ver == ver]
+            if self.prefill_rows is not None:
+                group = sorted(group, key=lambda s: self._slots[s].seq)[
+                    :self.prefill_rows]
             with obs.span("serve.prefill") as sp:
+                if obs.tracing() and self.hybrid:
+                    # rows whose chunk continues a carried state
+                    sp.set_metadata(state_carried=sum(
+                        self._slots[s].filled > 0 for s in group))
                 (bcap, ccap), n_first, n_tokens = self._run_chunk(
-                    [s for s in todo if self._slots[s].ver == ver], ver,
-                    completed, now)
+                    group, ver, completed, now)
                 shapes.append((bcap, ccap))
                 if obs.tracing():
                     sp.set_metadata(batch=bcap, chunk=ccap, tokens=n_tokens,
@@ -826,7 +889,7 @@ class ServingEngine:
                                  jnp.asarray(tokens), jnp.asarray(off),
                                  jnp.asarray(cl), jnp.asarray(rids),
                                  jnp.asarray(rmap), jnp.asarray(wmap),
-                                 self.cache)
+                                 jnp.asarray(slots), self.cache)
         else:
             nxt, self.cache = fn(self._versions[ver],
                                  jnp.asarray(tokens),
@@ -878,7 +941,12 @@ class ServingEngine:
         verify dispatch (``rows_live``, ``kv_tokens_live`` and, paged,
         ``kv_pages_live``, ``kv_pages_walked``); each dispatch span holds
         ``mlitb.serve.readback``, where the host waits for the sampled
-        tokens."""
+        tokens. A ``layer_types`` model adds ``state_resets`` (slots
+        whose state admission zeroed) to the admit span,
+        ``state_carried`` (rows whose chunk starts from a carried state)
+        to the prefill span, and ``state_rows_live`` / ``state_rows_walked``
+        (rows whose state the decode program reads and writes) to the
+        decode span."""
         with obs.span("serve.step", queued=len(self._queue)) as sp:
             if obs.tracing():
                 sp.set_metadata(live_rows=self.n_live)
@@ -926,6 +994,7 @@ class ServingEngine:
             free = [s for s in range(self.max_batch)
                     if self._slots[s] is None]
             admitted = 0
+            taken: List[int] = []
             wait_max = None
             while self._queue and free:
                 req = self._queue[0]
@@ -943,10 +1012,12 @@ class ServingEngine:
                 self._slots[s] = _SlotState(
                     req=req, gen=[], ver=self.version, filled=reused,
                     pages=pages, n_shared=n_shared, inserted=n_shared,
-                    admitted=now)
+                    admitted=now, seq=self._admit_seq)
+                self._admit_seq += 1
                 self._pos[s] = reused
                 self._live[s] = False
                 admitted += 1
+                taken.append(s)
                 if now is not None:
                     wait = now - req.arrival
                     wait_max = wait if wait_max is None \
@@ -956,8 +1027,12 @@ class ServingEngine:
                 sum(1 for st in self._slots if st is not None))
             if self.paged:
                 self.pages_peak = max(self.pages_peak, self._pool.n_used)
+            if self.hybrid and taken:
+                self._reset_states(taken)
             if obs.tracing():
                 sp.set_metadata(admitted=admitted, shed=len(shed))
+                if self.hybrid:
+                    sp.set_metadata(state_resets=len(taken))
                 if wait_max is not None:
                     sp.set_metadata(queue_wait_max_s=float(wait_max))
         return admitted
@@ -1036,6 +1111,10 @@ class ServingEngine:
             args["kv_pages_live"] = int((pos // self.page_size).sum()) \
                 + len(pos)
             args["kv_pages_walked"] = self.pages_walked
+        if self.hybrid:
+            # the Mamba layers read and write every slot's state
+            args["state_rows_live"] = len(pos)
+            args["state_rows_walked"] = self.max_batch
         return args
 
     def _step_speculative(self, completed: List[Completion],

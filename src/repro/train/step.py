@@ -77,9 +77,12 @@ class ServePrograms:
 
       prefill(params, batch)                      -> (logits (B,1,V), cache)
       prefill_chunk  dense: (params, tokens (B,C), off, clen, cache)
-                     paged: (params, tokens, off, clen, pool, rmap, wmap)
+                     paged: (params, tokens, off, clen, pool, rmap, wmap,
+                     slots (B,)): slots picks the rows' recurrent state
+                     (layer_types only; other models ignore it)
       verify         same as prefill_chunk but returns ALL chunk logits
                      (B,C,V) — the speculative-verification program
+                     (None for layer_types: state cannot roll back)
       decode         dense: (params, token (B,1), pos (B,), cache, live)
                      paged: (params, token, pos, pool, live, rmap, wmap)
       decode_lockstep(params, token, pos_scalar, cache)   [dense only]
@@ -113,6 +116,13 @@ def build_serve_programs(cfg: ArchConfig, *, paged: bool,
     if decode_kernel not in ("xla", "flash"):
         raise ValueError(f"decode_kernel={decode_kernel!r}: expected "
                          f"'xla' or 'flash'")
+    if cfg.layer_types and (not paged or decode_kernel != "flash"):
+        # recurrent state beside KV pages: one layout, one decode path
+        raise ValueError(
+            f"{cfg.name}: a model with recurrent (Mamba) layers serves "
+            f"from the paged pool with decode_kernel='flash' only; "
+            f"{'the dense slot layout' if not paged else 'XLA decode'} "
+            f"is refused")
 
     def prefill(params, batch):
         kw = {}
@@ -125,9 +135,11 @@ def build_serve_programs(cfg: ArchConfig, *, paged: bool,
                           lengths=batch.get("lengths"), **kw)
 
     if paged:
-        def prefill_chunk(params, tokens, off, clen, pool, rmap, wmap):
+        def prefill_chunk(params, tokens, off, clen, pool, rmap, wmap,
+                          slots=None):
             return tf.prefill_chunk_paged(params, cfg, tokens, off, clen,
-                                          pool, rmap, wmap, unroll=unroll)
+                                          pool, rmap, wmap, unroll=unroll,
+                                          slots=slots)
 
         def verify(params, tokens, off, clen, pool, rmap, wmap):
             return tf.prefill_chunk_paged(params, cfg, tokens, off, clen,
@@ -144,6 +156,10 @@ def build_serve_programs(cfg: ArchConfig, *, paged: bool,
                 return tf.decode_step_ragged_paged(
                     params, cfg, token, pos, pool, live, rmap, wmap,
                     unroll=unroll)
+        if cfg.layer_types:
+            # no single-shot prefill, and no verify: state cannot roll
+            # back rejected drafts
+            prefill = verify = None
         return ServePrograms(cfg=cfg, paged=True,
                              decode_kernel=decode_kernel, prefill=prefill,
                              prefill_chunk=prefill_chunk, verify=verify,

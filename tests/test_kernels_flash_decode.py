@@ -120,6 +120,25 @@ def test_flash_decode_matches_ref(B, H, K, D, NP, ps, P):
     assert jnp.abs(out - ref).max() < 2e-5
 
 
+@pytest.mark.parametrize("scale", [1 / 64, 0.3])
+def test_flash_decode_scale_matches_ref(scale):
+    """A softmax scale other than ``D ** -0.5`` (Granite's 1/64 at
+    head_dim 64) reaches the kernel's logits as the reference's."""
+    case = _mk_case(jax.random.PRNGKey(64), 3, 8, 2, 64, 12, 8, 4)
+    out = flash_decode(*case, interpret=True, scale=scale)
+    ref = flash_decode_ref(*case, scale=scale)
+    assert jnp.abs(out - ref).max() < 2e-5
+    # and it is not the default's answer
+    assert jnp.abs(out - flash_decode_ref(*case)).max() > 1e-3
+
+
+def test_flash_decode_default_scale_is_bit_exact():
+    case = _mk_case(jax.random.PRNGKey(65), 3, 8, 2, 64, 12, 8, 4)
+    out = flash_decode(*case, interpret=True)
+    explicit = flash_decode(*case, interpret=True, scale=64 ** -0.5)
+    assert jnp.array_equal(out, explicit)
+
+
 def test_flash_decode_ragged_pos_and_dead_rows():
     """Rows at every fill level incl. pos=0, plus dead rows: dead rows
     must come out EXACTLY zero (the engine discards them, but NaN would

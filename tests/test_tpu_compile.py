@@ -1,7 +1,8 @@
 """Compile the main path's Pallas kernels for a TPU v5e that is described,
 not attached (``jax.experimental.topologies``), at the published widths
 they run at: the TPU compiler refuses here what interpret mode cannot see
-(block shapes off the (8, 128) tiling, too much VMEM).
+(block shapes off the (8, 128) tiling, too much VMEM). The hybrid serve
+programs compile whole, and their memory must fit the chip.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the suite runs in several.
@@ -22,6 +23,11 @@ from repro.kernels.topk_compress import (as_blocks, block_topk,
 B, H, K, D, T = 8, 32, 8, 128, 1024
 # mlitb-lm-100m's flat parameter buffer, 4 workers
 N_FLAT, W = 100_682_496, 4
+# granite-4.0-h-micro served as its benchmark cell: 48 slots of 4096
+# tokens in 128-token pages, prefill chunks of 512 for up to 4 rows
+SLOTS, SEQ, PAGE, CHUNK, ROWS = 48, 4096, 128, 512, 4
+# the device memory a program may use on one v5e (of its 16 GiB)
+V5E_BYTES = 15.75e9
 
 
 @pytest.fixture(scope="module")
@@ -158,3 +164,56 @@ def test_reduce_step_scatters_keep_their_scopes(one_chip, method, kmax):
     # the new parameters: the segment-sum rebuilt inside their fusion
     assert re.search(r'op_name="[^"]*/reduce/segment_sum/[^"]*eq"',
                      body(producer(outs[0])))
+
+
+@pytest.fixture(scope="module")
+def hybrid(one_chip):
+    """The published hybrid's serve programs, their parameters and cache
+    as shapes on the described chip."""
+    from repro.configs import get_config
+    from repro.models import transformer as tf
+    from repro.train.step import build_serve_programs
+
+    cfg = get_config("granite-4.0-h-micro")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+    pages = SLOTS * SEQ // PAGE
+    cache = jax.eval_shape(lambda: tf.hybrid_cache(cfg, SLOTS, pages, PAGE))
+    return (build_serve_programs(cfg, paged=True, decode_kernel="flash"),
+            on_chip(tf.abstract_params(cfg)), on_chip(cache))
+
+
+def _fits(compiled):
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert used <= V5E_BYTES, used
+    # the cache is donated: updated in place, never held twice
+    assert m.alias_size_in_bytes >= 0.99 * m.output_size_in_bytes - 1e8
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_hybrid_serve_programs_compile_and_fit(one_chip, hybrid,
+                                               monkeypatch, program):
+    import importlib
+    # the kernel compiles natively only where the default backend is a
+    # TPU: here it is the CPU, so the test asks for the native kernel
+    ops = importlib.import_module("repro.kernels.flash_decode.ops")
+    monkeypatch.setattr(ops, "default_interpret", lambda i=None: False)
+    progs, params, cache = hybrid
+
+    def S(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    P = SEQ // PAGE
+    if program == "decode":
+        compiled = jax.jit(progs.decode, donate_argnums=(3,)).lower(
+            params, S((SLOTS, 1)), S((SLOTS,)), cache, S((SLOTS,), bool),
+            S((SLOTS, P)), S((SLOTS, P))).compile()
+        assert compiled.as_text().count("tpu_custom_call") >= 4
+    else:
+        compiled = jax.jit(progs.prefill_chunk, donate_argnums=(4,)).lower(
+            params, S((ROWS, CHUNK)), S((ROWS,)), S((ROWS,)), cache,
+            S((ROWS, P)), S((ROWS, P)), S((ROWS,))).compile()
+    _fits(compiled)
